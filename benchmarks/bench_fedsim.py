@@ -4,15 +4,14 @@ oracle, pow-2 re-bucketing padding waste, delta-codec byte ratios +
 convergence-vs-bytes curves (identity / int8 / topk / signsgd / powersgd
 through the shared upload pipeline), and async event throughput.
 
-The throughput comparison runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the shard_map cohort
-axis needs >1 device; CPU-only hosts fake them) and measures *steady-state*
-seconds/round from per-round ``perf_counter`` marks (one ``on_round``
-callback per round), dropping the warmup intervals where jit compile time
-lands and taking the median of the rest — see benchmarks/common.py
-``steady_state``.  Clients are IID-partitioned so every cohort slot carries
-real work
-(dirichlet skew creates sub-batch clients that fall back to the sequential
+The throughput comparison runs in this process on the devices JAX finds
+(the shard_map cohort axis spans all of them; on a CPU host, fake several
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` on the command
+line) and measures *steady-state* seconds/round from per-round
+``perf_counter`` marks (one ``on_round`` callback per round), dropping the
+warmup intervals where jit compile time lands and taking the median of the
+rest — see benchmarks/common.py ``steady_state``.  Clients are
+IID-partitioned so every cohort slot carries real work (dirichlet skew creates sub-batch clients that fall back to the sequential
 path and padded slots that waste cohort compute — that regime is the
 round-robin fallback's job, not this benchmark's).
 
@@ -26,35 +25,31 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
-import textwrap
+import time
+
+import jax
+import numpy as np
 
 from benchmarks import common as C
+from benchmarks.common import steady_state
+from repro.configs.distilbert import MINI
+from repro.data.synthetic import make_classification
+from repro.federated.baselines import all_strategies
+from repro.federated.partition import dirichlet_partition, iid_partition
+from repro.federated.server import FedConfig, run_federated
+from repro.fedsim.cohort import build_cohort
+from repro.models import Model
 
 JSON_PATH = os.environ.get("BENCH_FEDSIM_JSON", "BENCH_fedsim.json")
-N_HOST_DEVICES = int(os.environ.get("BENCH_FEDSIM_DEVICES", "2"))
 
-_SUB = textwrap.dedent("""
-    import os, sys, json, time
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=%(ndev)d")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    from repro.configs.distilbert import MINI
-    from repro.data.synthetic import make_classification
-    from repro.federated.baselines import all_strategies
-    from repro.federated.partition import iid_partition
-    from repro.federated.server import FedConfig, run_federated
-    from repro.models import Model
 
-    quick = %(quick)r
+def _measure(quick: bool) -> dict:
+    """Time the runners on the devices this process has; returns the
+    JSON record ``main`` turns into CSV rows."""
     cfg = MINI.with_(n_layers=2, layer_pattern=("attn",) * 2)
     train = make_classification(1600, 20, cfg.vocab_size, 32, seed=1)
     test = make_classification(200, 20, cfg.vocab_size, 32, seed=2)
     parts = iid_partition(train.labels, 20, seed=0)
-
-    from benchmarks.common import steady_state
 
     def timed(runner, rounds, cpr, codec="identity"):
         # steady-state s/round: perf_counter marks at run start and after
@@ -118,7 +113,7 @@ _SUB = textwrap.dedent("""
         run_federated(model, strat, parts_f, train_f, test_f, fc,
                       on_round=lambda r, log: (
                           marks.append(time.perf_counter())
-                          if (r + 1) %% KK == 0 else None))
+                          if (r + 1) % KK == 0 else None))
         block_s, n = steady_state(marks[:-1], warmup=1)
         return block_s / KK, n
 
@@ -136,9 +131,6 @@ _SUB = textwrap.dedent("""
     # re-bucketing: mean padding waste (dead steps / rectangle area) on a
     # dirichlet-skewed split, with and without the pow-2 step-axis snap.
     # Host-side cohort construction only — no training.
-    import numpy as np
-    from repro.federated.partition import dirichlet_partition
-    from repro.fedsim.cohort import build_cohort
     sk = dirichlet_partition(train.labels, 40, alpha=0.3, seed=0)
     fcb = FedConfig(rounds=1, clients_per_round=8, batch_size=16,
                     max_local_batches=16)
@@ -183,23 +175,11 @@ _SUB = textwrap.dedent("""
                     "events": len(h["events"]),
                     "mean_staleness": sum(l.staleness for l in h["rounds"])
                     / max(len(h["rounds"]), 1)}
-    print("FEDSIM_JSON=" + json.dumps(out))
-""")
+    return out
 
 
 def main(quick: bool = False) -> None:
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    script = _SUB % {"ndev": N_HOST_DEVICES, "quick": bool(quick or C.QUICK)}
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=3000)
-    marker = "FEDSIM_JSON="
-    line = next((ln for ln in r.stdout.splitlines()
-                 if ln.startswith(marker)), None)
-    if r.returncode != 0 or line is None:
-        sys.stderr.write(r.stdout[-2000:] + r.stderr[-4000:])
-        raise RuntimeError("fedsim subprocess failed")
-    out = json.loads(line[len(marker):])
+    out = _measure(bool(quick or C.QUICK))
 
     rows = []
     for rec in out["rows"]:

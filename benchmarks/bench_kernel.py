@@ -1,7 +1,7 @@
 """Fused masked-BEA kernel: correctness delta vs oracle, measured wall time
-of the unfused XLA path (CPU), and the analytic HBM-traffic saving of the
-fused Pallas kernel on the TPU target (the fusion removes 3 HBM round-trips
-of the adapter intermediates)."""
+of the unfused XLA path on the device JAX finds, and the analytic
+HBM-traffic saving of the fused Pallas kernel (the fusion removes 3 HBM
+round-trips of the adapter intermediates)."""
 
 from __future__ import annotations
 

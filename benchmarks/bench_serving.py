@@ -104,8 +104,8 @@ def main(quick: bool = False):
                       latency=f"{res['mean_latency_steps']:.1f}",
                       decode_calls=res["decode_calls"])])
 
-    # batched kernel vs sequential per-request reference (interpret mode —
-    # relative trend only; TPU is the target)
+    # batched kernel vs sequential per-request reference (Mosaic on a TPU,
+    # interpret mode elsewhere — there a relative trend only)
     for g in ([2] if quick else [2, 4, 8]):
         t_b, t_s = _kernel_step(16, 64, 64, g, 8)
         rec = dict(name="serving/kernel", n_adapters=g, batched_s=t_b,

@@ -5,6 +5,8 @@ Prints ``name,value,derived`` CSV rows.  Env knobs:
   BENCH_ROUNDS=N    federated rounds per run
   BENCH_ONLY=a,b    run only the named benches
 
+Every bench runs in this one process, on the devices JAX finds.
+
 Usage: PYTHONPATH=src python -m benchmarks.run
 """
 
@@ -22,6 +24,7 @@ from benchmarks import (bench_ablation, bench_arbitration, bench_comm,
                         bench_secagg, bench_serving, bench_sweeps,
                         bench_variance)
 from benchmarks import common as C
+from repro.compat import enable_compilation_cache
 
 BENCHES = {
     "variance": bench_variance.main,          # Eqs 9/10
@@ -44,6 +47,7 @@ BENCHES = {
 
 
 def main() -> int:
+    enable_compilation_cache()
     quick = C.QUICK
     only = os.environ.get("BENCH_ONLY")
     names = [n.strip() for n in only.split(",")] if only else list(BENCHES)

@@ -1,39 +1,38 @@
-"""Version-drift shims shared across the repo.
+"""Process-wide JAX set-up shared by the entry points.
 
-jax.shard_map graduated from jax.experimental between the versions this
-repo targets, and the replication-check kwarg was renamed with it
-(check_rep → check_vma).  Import ``shard_map``/``SHARD_MAP_KWARGS`` from
-here instead of re-deriving the spelling locally.  The persistent
-compilation-cache knobs moved around similarly — use
-``enable_compilation_cache``.
+``enable_compilation_cache`` places JAX's persistent compilation cache so
+repeated runs (separate processes included) skip lowering and compilation.
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+module sets no other directory; otherwise the cache lives at one fixed path
+inside the checkout (``DEFAULT_CACHE_DIR``).  The path is part of the cache
+key, so it is never made from a temporary name, a process id or the time.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map, SHARD_MAP_KWARGS = jax.shard_map, {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-    SHARD_MAP_KWARGS = {"check_rep": False}
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compilation_cache(path: str) -> bool:
-    """Point jax's persistent compilation cache at ``path`` so repeated
-    sweeps (separate processes included) skip lowering+compilation.
+def enable_compilation_cache() -> str:
+    """Turn the persistent compilation cache on and return its directory.
 
-    The default activation thresholds (minimum entry size / minimum compile
-    time) would silently skip the small, fast CPU compiles this repo's test
-    models produce, so both are forced off — every executable is cached.
-    Returns False (and changes nothing) when this jax has no persistent
-    cache support."""
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        _cc.set_cache_dir(path)
-    except Exception:
-        return False
-    return True
+    Call before the first compile.  The activation thresholds (minimum entry
+    size / minimum compile time) would skip small, fast compiles, so both are
+    forced off and every executable is cached.  Raises ``OSError`` when the
+    directory cannot be created or written: a cache that silently does
+    nothing would make every run pay full compilation."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    os.makedirs(path, exist_ok=True)
+    if not os.access(path, os.W_OK):
+        raise OSError(f"compilation cache directory {path!r} is not writable")
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path

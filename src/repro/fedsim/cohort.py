@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_KWARGS as _SM_KW
-from repro.compat import shard_map as _shard_map
 from repro.data.synthetic import Dataset, batches as batch_iter
 
 
@@ -183,11 +181,11 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
         return params_c, grads_c, losses_c, metrics_c, avg
 
     cspec = P("clients")
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), cspec, P(), P(), cspec, cspec, cspec),
         out_specs=(cspec, cspec, cspec, cspec, P()),
-        **_SM_KW)
+        check_vma=False)
     return jax.jit(fn)
 
 

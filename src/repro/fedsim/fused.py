@@ -44,8 +44,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs as OBS
-from repro.compat import SHARD_MAP_KWARGS as _SM_KW
-from repro.compat import shard_map as _shard_map
 from repro.core import pruning as PR
 from repro.federated import server as SV
 from repro.fedsim import cohort as CH
@@ -123,11 +121,11 @@ def make_fused_fn(model, opt, task: str = "cls", mesh=None):
         return final, losses, metrics
 
     cspec = P(None, "clients")
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), cspec, cspec, cspec),
         out_specs=(P(), cspec, cspec),
-        **_SM_KW)
+        check_vma=False)
     # the carry is donated: params never re-materialize between rounds (on
     # backends without donation support this is a harmless no-op warning)
     return jax.jit(fn, donate_argnums=(1,))

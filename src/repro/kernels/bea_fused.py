@@ -16,8 +16,9 @@ TPU mapping (HBM→VMEM→MXU):
   footprint ≈ bm·bk + bk·bn + bm·bn·4 + r·(bk+bn) ≈ 1.1 MB at defaults —
   comfortably inside the ~16 MB v5e VMEM with double buffering.
 
-Validated against kernels/ref.py with interpret=True (this container is
-CPU-only; TPU is the target, not the runtime).
+On a TPU backend the kernel is compiled by Mosaic; elsewhere (the CPU test
+suite) it runs in Pallas interpret mode — see ``interpret_mode``.  Validated
+against kernels/ref.py in both.
 """
 
 from __future__ import annotations
@@ -53,6 +54,15 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, em_ref, out_ref, acc_ref, u_ref, *,
         out_ref[...] = (acc_ref[...] + scaling * delta).astype(out_ref.dtype)
 
 
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """The one place that decides whether a Pallas call interprets.
+
+    On a TPU backend a kernel never interprets.  Elsewhere it interprets
+    unless the caller passes ``interpret=False``, which lowers it through
+    Mosaic — how a test compiles it for a described, unattached TPU."""
+    return jax.default_backend() != "tpu" and interpret is not False
+
+
 def _pad_to(arr, mult, axis):
     size = arr.shape[axis]
     pad = (-size) % mult
@@ -67,7 +77,7 @@ def _pad_to(arr, mult, axis):
                                              "block_k", "interpret"))
 def bea_dense(x, w, a, b, e, mask, scaling: float = 1.0,
               block_m: int = 256, block_n: int = 256, block_k: int = 512,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Fused y = x@W + scaling·((x Aᵀ)⊙(e⊙m))Bᵀ.
 
     x: (M, K); w: (K, N); a: (r, K); b: (N, r); e/mask: (r,).
@@ -105,6 +115,6 @@ def bea_dense(x, w, a, b, e, mask, scaling: float = 1.0,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, wp, ap, bp, em)
     return out[:m0, :n0]

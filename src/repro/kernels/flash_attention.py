@@ -14,7 +14,8 @@ innermost; the kv-head index_map folds GQA (h → h // group) so grouped
 queries read the same KV tile without a copy.  Fully-masked causal tiles are
 skipped with pl.when.
 
-Validated against kernels/ref.py in interpret mode (CPU container).
+Compiled by Mosaic on a TPU backend, interpreted elsewhere
+(``bea_fused.interpret_mode``); validated against kernels/ref.py.
 """
 
 from __future__ import annotations
@@ -26,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.bea_fused import interpret_mode
 
 NEG_INF = -2.3819763e38
 
@@ -89,7 +92,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: float | None = None,
                     group: int = 1, block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (BH, Sq, hd); k/v: (BH // group, Sk, hd) → (BH, Sq, hd).
 
     ``group`` = GQA group size; kv tiles are indexed via h // group.
@@ -120,13 +123,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out
 
 
 def mha_flash(q, k, v, *, causal=True, window=0, softcap=0.0,
-              interpret=True, block_q=512, block_k=512):
+              interpret=None, block_q=512, block_k=512):
     """(B, S, H, hd) MHA/GQA wrapper around the kernel."""
     b, sq, h, hd = q.shape
     kvh = k.shape[2]

@@ -1,7 +1,7 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-``use_pallas("auto")`` → real Mosaic lowering on TPU, interpret mode on CPU
-(the kernel body executes in Python — correctness validation only).  The
+The kernels lower through Mosaic on a TPU backend and run in Pallas
+interpret mode elsewhere (``bea_fused.interpret_mode``).  The
 model layers call ``adapted_dense`` which routes to the fused kernel when
 enabled, otherwise the unfused jnp path (the dry-run default, so the HLO is
 analyzable op-by-op; §Perf swaps the kernel in and accounts the fusion win).
@@ -9,21 +9,11 @@ analyzable op-by-op; §Perf swaps the kernel in and accounts the fusion win).
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.bea_batched import bea_batched
 from repro.kernels.bea_fused import bea_dense
-
-_BACKEND_IS_TPU = None
-
-
-def _on_tpu() -> bool:
-    global _BACKEND_IS_TPU
-    if _BACKEND_IS_TPU is None:
-        _BACKEND_IS_TPU = jax.default_backend() == "tpu"
-    return _BACKEND_IS_TPU
 
 
 def adapted_dense(x, w, a, b, e, mask, scaling: float,
@@ -39,8 +29,7 @@ def adapted_dense(x, w, a, b, e, mask, scaling: float,
         return y + scaling * jnp.einsum("...r,nr->...n", u, b.astype(x.dtype))
     lead = x.shape[:-1]
     xm = x.reshape(-1, x.shape[-1])
-    ym = bea_dense(xm, w, a, b, e, mask, scaling=scaling,
-                   interpret=not _on_tpu())
+    ym = bea_dense(xm, w, a, b, e, mask, scaling=scaling)
     return ym.reshape(lead + (w.shape[1],))
 
 
@@ -57,7 +46,7 @@ def adapted_dense_multi(x, w, a_stack, b_stack, e_stack, m_stack, idx,
     """
     if use_kernel:
         return bea_batched(x, w, a_stack, b_stack, e_stack, m_stack, idx,
-                           scaling=scaling, interpret=not _on_tpu())
+                           scaling=scaling)
     g = a_stack.shape[0]
     if g == 0 or a_stack.shape[1] == 0:
         return jnp.dot(x, w.astype(x.dtype))

@@ -12,6 +12,9 @@ codec (int8 blockwise / top-k sparsification / 1-bit signsgd / low-rank
 powersgd, all with error feedback on the client→server *delta* wire) and
 ``--straggler`` / ``--dropout`` inject client heterogeneity.  ``--secagg``
 composes with field-exact codecs (``--codec signsgd``).
+
+Compiled programs persist in the directory ``JAX_COMPILATION_CACHE_DIR``
+names, or else in ``<checkout>/.jax_cache`` (``repro.compat``).
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import argparse
 import numpy as np
 
 from repro import obs
+from repro.compat import enable_compilation_cache
 from repro.configs.distilbert import MINI
 from repro.data.synthetic import make_classification
 from repro.federated.baselines import all_strategies
@@ -62,10 +66,6 @@ def main(argv=None):
                     help="cohort: re-bucket each round's step axis to the "
                          "next pow-2 of the cohort's real max local steps "
                          "(cuts padding waste on skewed partitions)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persist jax's compilation cache here so repeated "
-                         "sweeps skip lowering (repro.compat"
-                         ".enable_compilation_cache)")
     ap.add_argument("--codec", default="identity",
                     choices=["identity", "int8", "topk", "signsgd",
                              "powersgd"])
@@ -103,10 +103,7 @@ def main(argv=None):
                          "`python -m repro.obs top URL`); implies tracing "
                          "(in-memory only unless --trace)")
     args = ap.parse_args(argv)
-
-    if args.compile_cache:
-        from repro.compat import enable_compilation_cache
-        enable_compilation_cache(args.compile_cache)
+    enable_compilation_cache()
 
     live = None
     if args.trace or args.metrics_port is not None:

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.compat import enable_compilation_cache
 from repro.configs import ARCH_IDS, PAPER_IDS, get_config
 from repro.models import Model
 from repro.pytree import materialize
@@ -152,6 +153,7 @@ def main(argv=None):
                          "(Prometheus text), /healthz, /snapshot; implies "
                          "tracing (in-memory only unless --trace)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     if args.batch < 1:
         ap.error("--batch must be >= 1")
     if args.tenants < 1:

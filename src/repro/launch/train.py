@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim as OPT
+from repro.compat import enable_compilation_cache
 from repro.configs import ARCH_IDS, PAPER_IDS, get_config
 from repro.data.synthetic import make_lm_stream
 from repro.launch import steps as ST
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--schedule", default="linear",
                     choices=["linear", "cosine", "wsd", "constant"])
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = Model(cfg, peft=args.peft)

@@ -269,11 +269,9 @@ def attention(p: dict, x: jax.Array, cfg, *, mode: str = "train", ad=None,
             # Pallas flash kernel (kernels/flash_attention.py): VMEM-resident
             # score tiles — the TPU-native memory-roofline fix (§Perf).
             from repro.kernels.flash_attention import mha_flash
-            import jax as _jax
             o = mha_flash(q.reshape(b, sq, h, hd), k, v, causal=causal,
                           window=window if causal else 0,
                           softcap=cfg.attn_softcap,
-                          interpret=_jax.default_backend() != "tpu",
                           block_q=min(512, sq), block_k=min(512, sq))
             o = o.reshape(b, sq, kv, g, hd)
         elif sq <= 2048:

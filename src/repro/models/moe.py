@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import SHARD_MAP_KWARGS as _SM_KW
-from repro.compat import shard_map as _shard_map
 from repro.core import adapters as AD
 from repro.models import layers as L
 from repro.pytree import ParamMeta
@@ -280,10 +278,10 @@ def moe_apply(p, x, cfg, ctx, ad=None, masks=None):
         return _moe_local(xl, wg, adl, ml, cfg, e_loc, mp_idx, model_ax,
                           data_axes)
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, wspec, adspec, mspec),
         out_specs=(xspec, P()),
-        **_SM_KW,
+        check_vma=False,
     )(x, p, ad, masks)
     return y, aux

@@ -1,5 +1,6 @@
 """Flash-attention Pallas kernel: shape/dtype/feature sweeps vs the jnp
-oracle (interpret mode on CPU; TPU is the target)."""
+oracle (interpret mode on CPU; tests/test_tpu_compile.py compiles the kernel
+for the chip)."""
 
 import jax
 import jax.numpy as jnp

@@ -21,6 +21,7 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -296,9 +297,9 @@ def test_quantized_opt_state_converges(setup):
 # ---------------------------------------------------------------------------
 
 _CACHE_SCRIPT = textwrap.dedent("""
-    import sys
+    import os, sys
     from repro.compat import enable_compilation_cache
-    assert enable_compilation_cache(sys.argv[1])
+    assert enable_compilation_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
     from repro import obs
     from repro.configs.distilbert import MINI
     from repro.data.synthetic import make_classification
@@ -316,7 +317,7 @@ _CACHE_SCRIPT = textwrap.dedent("""
     fc = FedConfig(rounds=4, clients_per_round=3, batch_size=16,
                    max_local_batches=2, eval_every=4, lr=3e-3,
                    runner="cohort", fuse_rounds=4)
-    obs.configure(sys.argv[2], meta=obs.provenance({"cmd": "cache-test"}))
+    obs.configure(sys.argv[1], meta=obs.provenance({"cmd": "cache-test"}))
     h = run_federated(model, strat, parts, train, test, fc)
     obs.close()
     print("CACHE_RUN_OK", h["final_acc"])
@@ -332,12 +333,13 @@ def test_compilation_cache_across_processes(tmp_path):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     stats = []
     for i in (1, 2):
         trace = str(tmp_path / f"run{i}.jsonl")
-        r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, cache,
-                            trace], env=env, cwd=".", capture_output=True,
+        r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, trace],
+                           env=env, cwd=".", capture_output=True,
                            text=True, timeout=420)
         assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
         assert "CACHE_RUN_OK" in r.stdout
@@ -345,3 +347,30 @@ def test_compilation_cache_across_processes(tmp_path):
     assert stats[0]["cache_misses"] > 0          # run 1 populated the cache
     assert stats[1]["cache_misses"] == 0, stats[1]
     assert stats[1]["cache_hits"] > 0
+
+
+def test_compilation_cache_default_dir_is_in_checkout(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the cache lands at the one fixed
+    path inside the checkout; with it, JAX's own setting is left alone."""
+    import jax
+    from repro.compat import DEFAULT_CACHE_DIR, enable_compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = enable_compilation_cache()
+        assert path == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert DEFAULT_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+        assert enable_compilation_cache() == path      # fixed, not per run
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(Path(__file__).resolve().parents[1]
+                               / ".jax_cache"))
+        enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)

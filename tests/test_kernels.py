@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp oracle
-(interpret=True executes the kernel body on CPU; TPU is the target)."""
+(interpret=True executes the kernel body on CPU; tests/test_tpu_compile.py
+compiles the kernels for the chip)."""
 
 import jax
 import jax.numpy as jnp
