@@ -19,7 +19,7 @@ def make_train_step(model, opt: OPT.Optimizer, task: str = "cls",
     loss_fn = model.cls_loss if task == "cls" else model.lm_loss
 
     @jax.jit
-    def step(base, params, opt_state, masks, gate, batch):
+    def client_step(base, params, opt_state, masks, gate, batch):
         if train_base:
             def f(both):
                 return loss_fn(both["base"], both["trainable"], masks, batch,
@@ -43,25 +43,25 @@ def make_train_step(model, opt: OPT.Optimizer, task: str = "cls",
                               params, updates)
         return params, opt_state, g, gb, loss, metric
 
-    return step
+    return client_step
 
 
 def make_base_update_step(opt: OPT.Optimizer):
     """Sparse full-FT update of the base (SLoRA stage 1)."""
     @jax.jit
-    def step(base, opt_state, grads, gate):
+    def base_update_step(base, opt_state, grads, gate):
         updates, opt_state = opt.update(grads, opt_state, base)
         if gate is not None:
             updates = jax.tree.map(
                 lambda u, gt: u * jnp.asarray(gt, u.dtype), updates, gate)
         base = jax.tree.map(lambda p, u: p + u.astype(p.dtype), base, updates)
         return base, opt_state
-    return step
+    return base_update_step
 
 
 def make_eval_step(model, task: str = "cls"):
     @jax.jit
-    def step(base, params, masks, batch):
+    def eval_step(base, params, masks, batch):
         logits, _, _ = model.forward(base, params, masks, batch,
                                      mode="train", remat=False)
         if task == "cls":
@@ -71,7 +71,7 @@ def make_eval_step(model, task: str = "cls"):
         nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
                                    -1)[..., 0]
         return nll.mean()
-    return step
+    return eval_step
 
 
 def local_train(step_fn, base, trainable, masks, gate, opt, data_batches

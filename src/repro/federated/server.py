@@ -194,14 +194,22 @@ def pin_params(tree, masks=None, sharding=None):
 
 
 def _arbitrate(strategy, trainable, local_masks, masks, masks_np, rnd):
-    """FedArb + RankDet after aggregation → (trainable, masks, masks_np)."""
+    """FedArb + RankDet after aggregation → (trainable, masks, masks_np).
+    Traced as an ``arbitrate`` span counting ``pruned``: ranks switched off
+    this round."""
     if strategy.uses_masks():
+        tr = OBS.get_tracer()
+        asp = tr.begin("arbitrate", kind="stage", rnd=int(rnd))
+        live0 = MK.count_true(masks_np) if tr.enabled else 0
         strategy.last_aggregate = trainable   # FedARA-global ablation hook
         masks_np = strategy.arbitrate(rnd, local_masks, masks_np)
         masks = jax.tree.map(jnp.asarray, masks_np)
         trainable = dict(trainable,
                          adapters=COMM.prune_tree(trainable["adapters"],
                                                   masks_np))
+        if tr.enabled:
+            asp.count(pruned=live0 - MK.count_true(masks_np))
+        asp.end()
     return trainable, masks, masks_np
 
 
@@ -459,7 +467,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
             protocol_s = 0.0
 
         # rank trajectory → trace (FedARA's per-round allocation decision)
-        if OBS.get_tracer().enabled and masks_np:
+        if OBS.get_tracer().has_readers and masks_np:
             history.record_ranks(rnd, masks_np,
                                  votes=MK.vote_fractions(local_masks))
 

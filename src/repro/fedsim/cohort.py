@@ -167,7 +167,7 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
     local_phase = make_local_phase(model, opt, task)
     mesh = mesh if mesh is not None else cohort_mesh()
 
-    def body(base, stacked, masks, gate, bstacks, smasks, weights):
+    def cohort_round(base, stacked, masks, gate, bstacks, smasks, weights):
         params_c, grads_c, losses_c, metrics_c = jax.vmap(
             local_phase, in_axes=(None, 0, None, None, 0, 0))(
             base, stacked, masks, gate, bstacks, smasks)
@@ -182,7 +182,7 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
 
     cspec = P("clients")
     fn = jax.shard_map(
-        body, mesh=mesh,
+        cohort_round, mesh=mesh,
         in_specs=(P(), cspec, P(), P(), cspec, cspec, cspec),
         out_specs=(cspec, cspec, cspec, cspec, P()),
         check_vma=False)

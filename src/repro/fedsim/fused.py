@@ -98,7 +98,7 @@ def make_fused_fn(model, opt, task: str = "cls", mesh=None):
     local_phase = CH.make_local_phase(model, opt, task)
     mesh = mesh if mesh is not None else CH.cohort_mesh()
 
-    def body(base, trainable, masks, gate, bstacks, smasks, weights):
+    def fused_rounds(base, trainable, masks, gate, bstacks, smasks, weights):
         def round_body(carry, xs):
             bstack, smask, w = xs
             params_c, _, losses_c, metrics_c = jax.vmap(
@@ -122,7 +122,7 @@ def make_fused_fn(model, opt, task: str = "cls", mesh=None):
 
     cspec = P(None, "clients")
     fn = jax.shard_map(
-        body, mesh=mesh,
+        fused_rounds, mesh=mesh,
         in_specs=(P(), P(), P(), P(), cspec, cspec, cspec),
         out_specs=(P(), cspec, cspec),
         check_vma=False)
@@ -172,8 +172,10 @@ def run_fused(model, strategy, parts, train, test, fc,
     rnd = 0
     while rnd < fc.rounds:
         block = _block_rounds(rnd, K, fc)
+        tr = OBS.get_tracer()
 
         # ---- host precompute: selection + event draws in eager RNG order --
+        psp = tr.begin("prepare", kind="stage", fused=len(block))
         sels, dropss, slowss, cohorts = [], [], [], []
         for r in block:
             sel = rng.choice(len(parts), size=cpr, replace=False)
@@ -204,19 +206,25 @@ def run_fused(model, strategy, parts, train, test, fc,
                        for k in tmpl.batches}
             smasks = np.stack([m for _, m, _ in rows])
             weights = np.stack([w for _, _, w in rows])
+        psp.end()
 
-            dsp = OBS.get_tracer().begin("cohort_dispatch", kind="dispatch",
-                                         fused=len(block))
-            if OBS.get_tracer().enabled:
+        if tmpl is not None:
+            sig = {}
+            if tr.has_readers:
                 from repro.obs import profile as PROF
-                dsp.set(sig=PROF.shape_signature(
-                    trainable, bstacks, smasks, weights))
-            with OBS.annotate("cohort_dispatch"):
-                trainable, lc, mc = fused_fn(base, trainable, masks, gate,
-                                             bstacks, smasks, weights)
+                sig["sig"] = PROF.shape_signature(
+                    trainable, bstacks, smasks, weights)
+            dsp = tr.begin("cohort_dispatch", kind="dispatch",
+                           fused=len(block), **sig)
+            trainable, lc, mc = fused_fn(base, trainable, masks, gate,
+                                         bstacks, smasks, weights)
             dsp.end()
             # ONE device→host pull for the whole block's loss/metric stacks
+            ksp = tr.begin("cohort.pull", kind="stage")
+            if tr.enabled:
+                ksp.count(nbytes=lc.nbytes + mc.nbytes)
             lc, mc = jax.device_get((lc, mc))
+            ksp.end()
             lc = np.asarray(lc, np.float32)
 
         # ---- replay the block into the recorder (eager span/float order) --

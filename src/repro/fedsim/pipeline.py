@@ -325,9 +325,10 @@ class UploadPipeline:
         ``1 − mean pairwise cosine`` over unit-normalized wires, computed as
         ``(‖Σu‖² − n) / (n(n−1))`` — one O(n·d) pass, no pairwise matrix.
         This is the FeDeRA-style heterogeneity signal; the health monitor
-        alerts when dispersion crosses its threshold."""
+        alerts when dispersion crosses its threshold.  Computed only when a
+        reader is attached (``Tracer.has_readers``)."""
         tr = OBS.get_tracer()
-        if not tr.enabled or len(encoded) < 2:
+        if not tr.has_readers or len(encoded) < 2:
             return
         flat = [np.asarray(e.wire, np.float64).ravel() for e in encoded]
         if len({w.size for w in flat}) != 1:

@@ -116,6 +116,10 @@ def run_cohort(model, strategy, parts, train, test, fc,
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
+        tr = OBS.get_tracer()
+        # stage spans (siblings under the round, in round order): prepare,
+        # cohort_dispatch, cohort.pull, upload, aggregate/arbitrate, evaluate
+        psp = tr.begin("prepare", kind="stage")
         sel = rng.choice(len(parts), size=cpr, replace=False)
         # ---- CommPru'd broadcast (delta-coded when a codec is on) --------
         if masks_np is not None:
@@ -140,19 +144,23 @@ def run_cohort(model, strategy, parts, train, test, fc,
         cohort_idx = {}
         if cohort is not None:
             stacked = CH.stack_params(bc, len(cohort.weights))
+        psp.end()
+        if cohort is not None:
             # dispatch span keyed by shape signature: any jax compile fired
             # inside parents under this span, so obs.profile attributes the
-            # compile to the exact argument shapes that caused the retrace
-            dsp = OBS.get_tracer().begin("cohort_dispatch", kind="dispatch")
-            if OBS.get_tracer().enabled:
+            # compile to the exact argument shapes that caused the retrace.
+            # The span wraps the launch alone (the signature is computed
+            # before it opens).
+            sig = {}
+            if tr.has_readers:
                 from repro.obs import profile as PROF
-                dsp.set(sig=PROF.shape_signature(
+                sig["sig"] = PROF.shape_signature(
                     stacked, cohort.batches, cohort.step_mask,
-                    cohort.weights))
-            with OBS.annotate("cohort_dispatch"):
-                pc, gc, lc, mc, avg = cohort_fn(
-                    base, stacked, masks, gate, cohort.batches,
-                    cohort.step_mask, cohort.weights)
+                    cohort.weights)
+            dsp = tr.begin("cohort_dispatch", kind="dispatch", **sig)
+            pc, gc, lc, mc, avg = cohort_fn(
+                base, stacked, masks, gate, cohort.batches,
+                cohort.step_mask, cohort.weights)
             dsp.end()
             cohort_idx = {cid: i for i, cid in enumerate(cohort.cids)}
             # ONE batched device→host pull for everything the host path
@@ -160,10 +168,18 @@ def run_cohort(model, strategy, parts, train, test, fc,
             # the loss/metric stacks; the per-client params/deltas below
             # are host-side slices of these, not per-leaf transfers inside
             # the client loop.
-            pc_host, bc_host, gc_host, lc, mc = jax.device_get(
-                (pc, bc,
-                 gc if strategy.uses_masks() and gc is not None else None,
-                 lc, mc))
+            pull = (pc, bc,
+                    gc if strategy.uses_masks() and gc is not None else None,
+                    lc, mc)
+            ksp = tr.begin("cohort.pull", kind="stage")
+            if tr.enabled:
+                ksp.count(nbytes=sum(x.nbytes for x in jax.tree.leaves(pull)))
+            pc_host, bc_host, gc_host, lc, mc = jax.device_get(pull)
+            ksp.end()
+            del pull    # or it holds this round's outputs through the next
+
+        usp = tr.begin("upload", kind="stage")
+        if cohort is not None:
             lc, mc = np.asarray(lc, np.float32), np.asarray(mc, np.float32)
             dc = jax.tree.map(
                 lambda p, b: np.asarray(p, np.float32)
@@ -187,6 +203,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
                      "n_batches": int(cohort.n_steps[i])}
                 w = float(cohort.weights[i])
             else:                                   # ragged client → oracle
+                fsp = tr.begin("fallback", kind="stage", cid=int(cid))
                 idx = parts[cid]
                 gen = SV._take(
                     batches(Dataset(train.tokens[idx], train.labels[idx]),
@@ -198,21 +215,28 @@ def run_cohort(model, strategy, parts, train, test, fc,
                     step_fn, base, bc, masks, gate, opt, gen)
                 delta_k = PL.delta_tree(params_k, bc)
                 w = float(len(parts[cid]))
+                fsp.count(steps=m["n_batches"], rows=len(idx))
+                fsp.end()
             lm = None
             if strategy.uses_masks():
+                isp = tr.begin("importance", kind="stage")
                 lm = strategy.local_masks(
                     rnd, params_k["adapters"],
                     (grads_k or {}).get("adapters"), n_rank_units)
+                isp.end()
                 local_masks.append(lm)
             upd = PL.ClientUpdate(int(cid), delta_k,
                                   weight=w, votes=lm,
                                   n_steps=m["n_batches"])
+            esp = tr.begin("encode", kind="stage")
             enc = pipe.encode(upd, masks_np)
+            esp.end()
             up += enc.nbytes
             encoded.append(enc)
             results.append((w, m))
             csp.end(n_steps=m["n_batches"], up_bytes=enc.nbytes,
                     loss=m["loss"])
+        usp.end()
 
         # ---- aggregation: on-device psum unless a side path was taken ----
         protocol_s = 0.0
@@ -239,7 +263,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
                 strategy, trainable, local_masks, masks, masks_np, rnd)
 
         # rank trajectory → trace (FedARA's per-round allocation decision)
-        if OBS.get_tracer().enabled and masks_np:
+        if tr.has_readers and masks_np:
             history.record_ranks(rnd, masks_np,
                                  votes=MK.vote_fractions(local_masks))
 
@@ -391,7 +415,7 @@ def run_async(model, strategy, parts, train, test, fc,
                         n_rank_units))
             trainable, masks, masks_np = SV._arbitrate(
                 strategy, trainable, local_masks, masks, masks_np, agg)
-            if OBS.get_tracer().enabled and masks_np:
+            if OBS.get_tracer().has_readers and masks_np:
                 history.record_ranks(agg, masks_np,
                                      votes=MK.vote_fractions(local_masks))
             live = (int(MK.count_true(masks_np)) if masks_np

@@ -16,7 +16,6 @@ zero-cost surface.
     $ python -m repro.obs diff a.jsonl b.jsonl --rel-tol 0.02
     $ python -m repro.obs chrome trace.jsonl      # → Perfetto
     $ python -m repro.obs report trace.jsonl -o report.html
-    $ python -m repro.obs regress fresh_BENCH.json BENCH_fedsim.json
 
 Live plane (``--metrics-port`` in the launch CLIs, or ``serve_live()``):
 
@@ -32,8 +31,9 @@ rank_trajectory / rollup_summary), record.py (RunRecorder: the runners'
 history dict as a view over the trace, rank_alloc events, cohort-scale
 trace sampling), health.py (streaming alert detectors), profile.py
 (compile accounting + memory watermarks), live.py (/metrics /healthz
-/snapshot HTTP plane), top.py (ANSI live viewer), regress.py (bench
-regression sentinel), report.py (static HTML/terminal report).
+/snapshot HTTP plane), top.py (ANSI live viewer), report.py (static
+HTML/terminal report).  While ``jax.profiler`` traces, every enabled span
+is also a profiler annotation on the device trace's clock.
 """
 
 from repro.obs.export import (chrome_trace, check, diff, provenance,
@@ -45,7 +45,7 @@ from repro.obs.live import LiveServer, serve_live
 from repro.obs.record import RunRecorder
 from repro.obs.sketch import Reservoir, Sketch
 from repro.obs.trace import (NULL_TRACER, Lazy, NullTracer, Span, Tracer,
-                             annotate, close, configure, disable, get_tracer)
+                             close, configure, disable, get_tracer)
 
 
 def get_metrics():
@@ -56,7 +56,7 @@ def get_metrics():
 
 __all__ = [
     "configure", "disable", "close", "get_tracer", "get_metrics",
-    "annotate", "Tracer", "NullTracer", "NULL_TRACER", "Span", "Lazy",
+    "Tracer", "NullTracer", "NULL_TRACER", "Span", "Lazy",
     "RunRecorder", "read_jsonl", "write_jsonl", "chrome_trace",
     "summarize", "check", "diff", "provenance", "rank_trajectory",
     "rollup_summary", "HealthMonitor", "Thresholds", "health_scan",

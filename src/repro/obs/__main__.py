@@ -1,5 +1,5 @@
 """Trace triage CLI:
-``python -m repro.obs summarize|diff|check|chrome|regress|report|top``.
+``python -m repro.obs summarize|diff|check|chrome|report|top``.
 
   summarize trace.jsonl [--format human|json]
       Reconstruct run-level accounting (comm_gb / sim_time_s / secagg
@@ -15,10 +15,6 @@
       Convert to Chrome trace-event JSON (load in Perfetto or
       about://tracing).  An empty / span-less trace converts to a valid
       (empty) Chrome trace rather than erroring.
-  regress fresh_BENCH.json committed_BENCH.json [--time-tol ...]
-      Bench regression sentinel: noise-aware comparison of a fresh bench
-      run against the committed trajectory; exit 1 on regression (CI
-      gate — see ``repro.obs.regress``).
   report trace.jsonl [-o report.html]
       Static report (rank heatmap, bytes by codec × stage, alert
       timeline, compile counts); terminal rendering by default, one
@@ -112,27 +108,6 @@ def _cmd_chrome(args) -> int:
     return 0
 
 
-def _cmd_regress(args) -> int:
-    from repro.obs import regress as R
-    tol = R.Tolerances(time_tol=args.time_tol,
-                       speedup_tol=args.speedup_tol,
-                       byte_tol=args.byte_tol,
-                       metric_tol=args.metric_tol)
-    if args.quantile_tol is not None:
-        tol.quantile_tol = args.quantile_tol
-    try:
-        fresh, committed = R.load(args.fresh), R.load(args.committed)
-    except (OSError, json.JSONDecodeError) as e:
-        print(f"unreadable bench json: {e}", file=sys.stderr)
-        return 1
-    res = R.compare(fresh, committed, tol)
-    if args.format == "json":
-        print(json.dumps(res, indent=1))
-    else:
-        print(R.format_report(res, args.fresh, args.committed))
-    return 0 if res["ok"] else 1
-
-
 def _cmd_report(args) -> int:
     from repro.obs import report as REP
     rep = REP.build_report(E.read_jsonl(args.trace))
@@ -182,24 +157,6 @@ def main(argv=None) -> int:
     p.add_argument("trace")
     p.add_argument("-o", "--out", default=None)
     p.set_defaults(fn=_cmd_chrome)
-
-    p = sub.add_parser("regress",
-                       help="bench regression sentinel (CI gate)")
-    p.add_argument("fresh", help="fresh BENCH_*.json")
-    p.add_argument("committed", help="committed BENCH_*.json baseline")
-    p.add_argument("--time-tol", type=float, default=0.75,
-                   help="allowed one-sided slowdown fraction (default .75)")
-    p.add_argument("--speedup-tol", type=float, default=0.5,
-                   help="allowed one-sided speedup shrink (default .5)")
-    p.add_argument("--byte-tol", type=float, default=1e-6,
-                   help="two-sided relative byte drift (default 1e-6)")
-    p.add_argument("--metric-tol", type=float, default=0.15,
-                   help="two-sided relative loss/acc drift (default .15)")
-    p.add_argument("--quantile-tol", type=float, default=None,
-                   help="two-sided drift for sketch-backed pNN keys "
-                        "(default: 2x the sketch relative-error bound)")
-    p.add_argument("--format", choices=["human", "json"], default="human")
-    p.set_defaults(fn=_cmd_regress)
 
     p = sub.add_parser("report", help="static run report from the JSONL")
     p.add_argument("trace")

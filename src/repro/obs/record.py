@@ -100,7 +100,7 @@ class RunRecorder(dict):
                  sim_time_s=self["sim_time_s"], comm_gb=self["comm_gb"],
                  loss=log.loss, acc=log.acc)
         tr = self._tr
-        if tr.enabled:
+        if tr.has_readers:
             # device-memory watermark at the round boundary (repro.obs
             # .profile; silently nothing on backends without memory stats)
             from repro.obs import profile as _profile

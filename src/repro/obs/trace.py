@@ -8,7 +8,11 @@ call, never an allocation, a string format, or (critically) a host sync.
 When enabled (``configure(path=...)``), spans buffer in memory as plain
 dicts and are written once at ``close()`` as JSONL (one event per line; see
 ``repro.obs.export`` for the schema, the Chrome-trace converter, and the
-``summarize``/``diff``/``check`` CLI).
+``summarize``/``diff``/``check`` CLI).  Each enabled span is also a
+``jax.profiler.TraceAnnotation`` of the same name, entered at ``begin`` and
+exited at ``end``: while ``jax.profiler`` traces, the span lands on the
+profiler's host plane, on the device trace's clock, with its scalar
+``begin`` attributes and its ``count`` counters as event stats.
 
 RL2 compliance (host-sync-in-hot-path): attribute values that live on
 device are recorded through :meth:`Span.lazy`, which stores the device
@@ -25,6 +29,7 @@ stamps ``sim_t0``/``sim_dur`` from it alongside the wall clock.
 from __future__ import annotations
 
 import json
+import numbers
 import random
 import time
 
@@ -65,6 +70,31 @@ class Lazy:
         return f"Lazy({self.value!r}, resolved={self.resolved})"
 
 
+_ANNOTATION = None     # jax.profiler.TraceAnnotation; False without jax
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+def _annotate(name: str, attrs: dict):
+    """Enter a profiler annotation carrying the scalar attributes."""
+    cls = _annotation_cls()
+    if not cls:
+        return None
+    ann = cls(name, **{k: v for k, v in attrs.items()
+                       if isinstance(v, (numbers.Number, str))})
+    ann.__enter__()
+    return ann
+
+
 def _json_default(o):
     if isinstance(o, Lazy):
         o = o.value
@@ -75,10 +105,14 @@ def _json_default(o):
 
 
 class Span:
-    """One timed region.  Usable as a context manager or via begin()/end()."""
+    """One timed region.  Usable as a context manager or via begin()/end().
+
+    Attributes given at ``begin`` and through ``count`` also reach the
+    profiler annotation; those given to ``set`` or ``end`` reach the JSONL
+    only."""
 
     __slots__ = ("name", "kind", "sid", "parent", "attrs", "_tr", "_t0",
-                 "_sim0", "_done")
+                 "_sim0", "_done", "_ann")
 
     def __init__(self, tracer, name, kind, sid, parent, attrs):
         self._tr = tracer
@@ -90,9 +124,18 @@ class Span:
         self._t0 = tracer._now()
         self._sim0 = tracer.sim_time
         self._done = False
+        self._ann = _annotate(name, attrs)
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        return self
+
+    def count(self, **counters) -> "Span":
+        """Record counters known before ``end`` on the span and on its
+        profiler annotation (as event stats)."""
+        self.attrs.update(counters)
+        if self._ann is not None:
+            self._ann.set_metadata(**counters)
         return self
 
     def lazy(self, key, value) -> "Span":
@@ -107,6 +150,9 @@ class Span:
         if self._done:
             return
         self._done = True
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if attrs:
             self.attrs.update(attrs)
         tr = self._tr
@@ -145,6 +191,8 @@ class Tracer:
         self.sample_seed = sample_seed
         # live telemetry plane (obs.live.LiveServer) when attached
         self.live = None
+        # obs.profile's compile listener routes here (set by configure)
+        self.compile_listener = False
         self._t_origin = time.perf_counter()
         self._events: list[dict] = [{
             "type": "meta", "schema": SCHEMA_VERSION,
@@ -156,6 +204,21 @@ class Tracer:
 
     def _now(self) -> float:
         return time.perf_counter() - self._t_origin
+
+    @property
+    def has_readers(self) -> bool:
+        """Whether anything reads the events besides the profiler: a JSONL
+        path, a subscriber (the health monitor), the live plane or the
+        compile listener.  Work whose only product is an event or a metric
+        (drift, rank trajectory, memory samples, shape signatures) runs
+        only when this holds; spans are recorded either way.
+
+        Reading ``events()``, ``close()``'s list or ``get_metrics()`` in
+        process does not count: such a caller attaches a reader (a path,
+        ``health=True`` or ``subscribe``), or it gets the spans without
+        the ``drift``/``rank_alloc``/``memory`` events and their metrics."""
+        return bool(self.path or self._subs or self.live is not None
+                    or self.compile_listener)
 
     # ---- live event stream -------------------------------------------------
 
@@ -278,6 +341,9 @@ class _NullSpan:
     def lazy(self, key, value):
         return self
 
+    def count(self, **counters):
+        return self
+
     def end(self, **attrs):
         return None
 
@@ -301,6 +367,7 @@ class NullTracer:
     client_sample = None
     sample_seed = 0
     live = None
+    has_readers = False
 
     def begin(self, name, kind="span", **attrs):
         return NULL_SPAN
@@ -344,6 +411,12 @@ def configure(path: str | None = None, enabled: bool = True,
     open round/dispatch span — see ``repro.obs.profile``).  Both are no-ops
     until events flow, and profile degrades to nothing when jax is absent.
 
+    With no ``path``, ``health=False`` and ``profile=False`` nothing but
+    the profiler reads the tracer (``Tracer.has_readers`` is False): spans
+    still buffer and annotate, and event-only work is skipped, so the
+    buffered events and the metrics lack drift, rank allocation and memory
+    samples.  A caller that reads those in process passes a reader.
+
     ``client_sample`` in (0, 1) head-samples per-client spans at round
     boundaries (deterministic by ``(sample_seed, round, client)``, tail-keep
     on alert, cohort rollup sketches preserved — see ``repro.obs.record``)."""
@@ -356,7 +429,7 @@ def configure(path: str | None = None, enabled: bool = True,
             _health.attach(_TRACER)
         if profile:
             from repro.obs import profile as _profile
-            _profile.install()
+            _TRACER.compile_listener = _profile.install()
     return _TRACER
 
 
@@ -376,29 +449,3 @@ def close() -> list[dict]:
     evs = _TRACER.close()
     _TRACER = NULL_TRACER
     return evs
-
-
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL_CTX = _NullCtx()
-
-
-def annotate(name: str):
-    """Optional ``jax.profiler`` trace annotation around a dispatch site
-    (cohort dispatch, BEA kernels).  A shared no-op context when tracing is
-    disabled or jax's profiler is unavailable — never a hard jax dep."""
-    if not _TRACER.enabled:
-        return _NULL_CTX
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return _NULL_CTX

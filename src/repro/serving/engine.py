@@ -234,7 +234,8 @@ class ServingEngine:
         n = req.prompt_len
         chunk = min(_pow2_floor(n), n) if self.chunk_prefill else n
         toks = jnp.asarray(req.prompt[:chunk], jnp.int32)[None]      # (1, C)
-        with OBS.annotate("serve.prefill"):
+        with OBS.get_tracer().begin("serve.prefill", kind="serving",
+                                    tokens=chunk):
             logits, new_cache = self._prefill_fn(
                 self.base, entry.adapters, entry.masks, toks,
                 self._zero_slot_cache)
@@ -283,7 +284,7 @@ class ServingEngine:
                            jnp.int32)[:, None]        # (k_pad, 1, 1)
         ad, msk = self._stacked(padded)
         sub = jax.tree.map(lambda v: v[rows], self.cache)
-        with OBS.annotate("serve.decode"):
+        with OBS.get_tracer().begin("serve.decode", kind="serving", rows=k):
             logits, new_sub = self._decode_fn(self.base, ad, msk, toks, sub)
         self.decode_calls += 1
         OBS.get_metrics().counter("serve.decode_tokens").inc(k)

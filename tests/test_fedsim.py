@@ -70,6 +70,34 @@ def test_cohort_matches_sequential_oracle(setup):
     assert h_coh["sim_time_s"] > 0.0
 
 
+def test_cohort_outputs_are_freed_before_the_next_dispatch(setup,
+                                                         monkeypatch):
+    """A round's per-client params, grads and loss stacks live on the
+    device until the host has pulled them, and no longer: none of them is
+    alive when the next round's cohort program launches (only the on-device
+    FedAvg, the next broadcast's source, may be)."""
+    import weakref
+    from repro.fedsim import cohort as CH
+    real = CH.make_cohort_fn
+    held = []
+
+    def make(*a, **kw):
+        fn = real(*a, **kw)
+
+        def launch(*args):
+            held.append(sum(r() is not None for r in refs))
+            refs.clear()
+            pc, gc, lc, mc, avg = out = fn(*args)
+            refs.extend(weakref.ref(x) for x in
+                        jax.tree.leaves((pc, gc, lc, mc)))
+            return out
+        return launch
+    refs: list = []
+    monkeypatch.setattr(CH, "make_cohort_fn", make)
+    _run(setup, "cohort", rounds=3)
+    assert len(held) == 3 and held == [0, 0, 0]
+
+
 def test_cohort_simulates_stragglers_and_dropout(setup):
     h = _run(setup, "cohort", strategy="fedlora", dropout=0.3,
              straggler=0.5, event_seed=3)

@@ -112,11 +112,9 @@ def test_disabled_tracer_is_shared_noop():
     assert h.value == h.summary() == {"count": 0, "sum": 0.0,
                                       "min": None, "max": None}
     assert h.quantile(0.5) is None and h.count == 0
-    # annotate is a shared no-op context when disabled
-    ctx = obs.annotate("cohort_dispatch")
-    with ctx:
-        pass
-    assert ctx is obs.annotate("again")
+    # counters on the null span are shared no-ops too
+    assert NULL_SPAN.count(nbytes=1) is NULL_SPAN
+    assert not tr.has_readers
 
 
 def test_lazy_attrs_resolve_in_one_batch(tmp_path):

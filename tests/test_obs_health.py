@@ -1,7 +1,7 @@
-"""Health detectors, the bench regression sentinel, and the forensics report.
+"""Health detectors and the forensics report.
 
 Everything here is stdlib-only by design (no jax import): the detectors,
-``regress``, and ``report`` all operate on plain dicts read back from JSONL,
+and ``report`` operate on plain dicts read back from JSONL,
 so the whole active-observability surface is testable without an accelerator.
 
 The synthetic fixtures below pin the *exact* alert payloads — the alert
@@ -21,11 +21,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 from repro.obs import export as E  # noqa: E402
 from repro.obs import health as H  # noqa: E402
 from repro.obs import profile as P  # noqa: E402
-from repro.obs import regress as R  # noqa: E402
 from repro.obs import report as REP  # noqa: E402
 from repro.obs.__main__ import main as obs_main  # noqa: E402
-
-REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -168,105 +165,6 @@ def test_live_attach_mirrors_scan():
     emb = H.embedded_alerts(evs)
     assert len(emb) == 1 and emb[0]["alert"] == "nan_loss"
     assert H.scan(evs) == emb
-
-
-# ---------------------------------------------------------------------------
-# regress: the bench regression sentinel
-# ---------------------------------------------------------------------------
-
-def _mini_bench():
-    return {
-        "ndev": 2,
-        "rows": [{"cpr": 4, "seq_round_s": [1.0, 1.1, 0.9],
-                  "cohort_round_s": [0.5, 0.55, 0.45],
-                  "seq_samples": 3, "cohort_samples": 3,
-                  "noisy": False, "speedup": 2.0},
-                 {"cpr": 8, "seq_round_s": [2.0], "cohort_round_s": [1.0],
-                  "noisy": True, "speedup": 2.0}],        # noisy row: dropped
-        "codec": {"identity": 1000, "topk": 120},
-        "convergence": {"fedlora": [[100, 2.0], [200, 1.5]]},
-        "async": {"wall_s": 3.0, "events": 50, "mean_staleness": 1.2},
-    }
-
-
-def test_regress_self_compare_passes():
-    res = R.compare(_mini_bench(), _mini_bench())
-    assert res["ok"] and res["failures"] == []
-    assert len(res["checked"]) > 0
-
-
-def test_regress_catches_median_slowdown():
-    fresh = _mini_bench()
-    fresh["rows"][0]["cohort_round_s"] = [1.0, 1.1, 0.9]   # 2x median
-    res = R.compare(fresh, _mini_bench())
-    assert not res["ok"]
-    assert any("cohort_round_s" in f["key"] for f in res["failures"])
-
-
-def test_regress_speedup_is_one_sided():
-    fresh = _mini_bench()
-    fresh["rows"][0]["speedup"] = 10.0                     # faster: fine
-    assert R.compare(fresh, _mini_bench())["ok"]
-    fresh["rows"][0]["speedup"] = 0.5                      # collapsed: fail
-    res = R.compare(fresh, _mini_bench())
-    assert not res["ok"]
-    assert any("speedup" in f["key"] for f in res["failures"])
-
-
-def test_regress_missing_and_extra_keys_never_fail():
-    fresh = _mini_bench()
-    del fresh["async"]                                     # quick-mode shape
-    fresh["rows"] = fresh["rows"][:1]
-    committed = _mini_bench()
-    committed["extra_section"] = {"x_s": 1.0}
-    res = R.compare(fresh, committed)
-    assert res["ok"]
-    assert res["only_committed"]                           # reported, not fatal
-
-
-def test_regress_noisy_and_info_keys_are_informational():
-    fresh = _mini_bench()
-    fresh["rows"][1]["cohort_round_s"] = [99.0]            # noisy row ignored
-    fresh["async"]["wall_s"] = 99.0                        # async: info only
-    assert R.compare(fresh, _mini_bench())["ok"]
-
-
-def test_regress_classify():
-    assert R.classify("rows.cpr4.cohort_round_s") == "time"
-    assert R.classify("rows.cpr4.speedup") == "speedup"
-    assert R.classify("codec.topk") == "bytes"
-    assert R.classify("convergence.fedlora.loss1") == "metric"
-    assert R.classify("async.wall_s") == "info"
-    assert R.classify("rows.cpr4.seq_samples") == "info"
-
-
-def test_regress_against_committed_bench(tmp_path, capsys):
-    """The committed BENCH_fedsim.json must pass against itself through the
-    real CLI — exit 0, and exit 1 once a 2x slowdown is injected."""
-    committed = str(REPO / "BENCH_fedsim.json")
-    assert obs_main(["regress", committed, committed]) == 0
-    out = capsys.readouterr().out
-    assert "RESULT: PASS" in out
-
-    bench = json.load(open(committed))
-    for row in bench["rows"]:
-        # eager rows carry cohort_round_s; fused rows fused_round_s
-        key = ("cohort_round_s" if "cohort_round_s" in row
-               else "fused_round_s")
-        v = row[key]
-        row[key] = [2 * x for x in v] if isinstance(v, list) else 2 * v
-    slow = str(tmp_path / "slow.json")
-    json.dump(bench, open(slow, "w"))
-    assert obs_main(["regress", slow, committed]) == 1
-    assert "RESULT: REGRESSION" in capsys.readouterr().out
-
-
-def test_regress_cli_json_format(tmp_path, capsys):
-    committed = str(REPO / "BENCH_fedsim.json")
-    assert obs_main(["regress", committed, committed,
-                     "--format", "json"]) == 0
-    res = json.loads(capsys.readouterr().out)
-    assert res["ok"] and res["failures"] == []
 
 
 # ---------------------------------------------------------------------------
