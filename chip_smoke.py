@@ -392,14 +392,17 @@ def _cohort_split(cfg, seed: int, parts, train, cpr: int) -> str:
     rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     ndev = len(jax.devices())
     sel = rng.choice(len(parts), size=cpr, replace=False)
+    c_pad = -(-cpr // ndev) * ndev
     cohort = CH.build_cohort(train, parts, [int(c) for c in sel], fc, 0,
-                             -(-cpr // ndev) * ndev)
+                             c_pad, ndev=ndev)
+    plan = CH.schedule(cohort.n_steps, cohort.step_mask.shape[1],
+                       CH.lane_count(parts, fc, c_pad, ndev), ndev)
     base, _ = SV.pin_params(base, sharding=rep)
     bc, masks = SV.pin_params(trainable, masks, sharding=rep)
     fn = CH.make_cohort_fn(model, opt, fc.task, mesh=mesh)
     pc, _, lc, _, _ = fn(base, CH.stack_params(bc, len(cohort.weights)),
                          masks, strat.optimizer_gate(bc, None),
-                         cohort.batches, cohort.step_mask, cohort.weights)
+                         cohort.batches, plan, cohort.weights)
     leaf = jax.tree.leaves(pc)[0]
     shards = sorted((s.device.id, s.data.shape[0])
                     for s in leaf.addressable_shards)

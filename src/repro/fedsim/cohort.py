@@ -5,12 +5,18 @@ with a Python loop over jitted steps — ``clients_per_round × local_batches``
 dispatches per round.  Here the whole local-training phase is ONE dispatch:
 
   - per-client params / optimizer states are stacked on a leading cohort axis,
-  - local SGD runs as ``lax.scan`` over local batches inside ``vmap`` over
-    clients (uneven client data handled by padding + per-client step masks:
-    a padded step computes and then discards, so real steps are bit-identical
-    in structure to the oracle's),
+  - local SGD runs as a loop over client-steps on W ≤ C lanes: the host
+    ``schedule`` packs each client's real steps, in order, into a
+    (T_cap, W) table, and every iteration gathers the W scheduled clients'
+    state and batches, runs the oracle's step ``vmap``-ped over the lanes
+    and scatters the results back.  Uneven clients take only their real
+    steps, and dead (weight-0) slots none: a padded step is never computed,
   - the cohort axis is ``shard_map``-ped across ``jax.devices()`` with an
     on-device ``psum`` weighted FedAvg, so aggregation needs no host gather.
+
+The lane count W comes from the partition (``lane_count``): enough lanes
+for an average cohort's steps in T iterations.  A population whose clients
+all fill T steps gets W = C and T iterations, the rectangle's work.
 
 Clients whose data is smaller than one batch (ragged trailing batch) cannot
 join the rectangle; ``build_cohort`` reports them as fallbacks and the runner
@@ -21,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,13 +50,98 @@ class Cohort:
     batches: dict                 # key -> (C, T, B, ...) np arrays
     step_mask: np.ndarray         # (C, T) bool — False for padded steps
     weights: np.ndarray           # (C,) f32 client data sizes (0 = pad slot)
-    cids: list[int]               # real client ids, stacked order
+    cids: list[int]               # real client ids
+    slots: list[int]              # stacked slot of each of ``cids``
     fallback: list[int]           # too-small clients → sequential path
     n_steps: np.ndarray           # (C,) int — real local steps per client
 
 
+def cohort_steps(n: int, fc) -> int:
+    """Local steps a client of ``n`` examples takes in the cohort program
+    (0 for a sub-batch client, which takes the sequential fallback)."""
+    if n < fc.batch_size:
+        return 0
+    return min(fc.max_local_batches, n // fc.batch_size) * fc.local_epochs
+
+
+def lane_count(parts: list, fc, c_pad: int, ndev: int) -> int:
+    """Lanes W of the cohort program, fixed for the run: enough for the
+    mean cohort's real steps in T iterations,
+    ``min(c_pad, ⌈cpr · n̄ / T⌉ rounded up to a multiple of ndev)``, with
+    ``n̄`` the mean of ``cohort_steps`` over every client.  A population
+    whose clients all fill T steps gets ``c_pad`` lanes."""
+    T = fc.max_local_batches * fc.local_epochs
+    cpr = min(fc.clients_per_round, len(parts))
+    total = sum(cohort_steps(len(p), fc) for p in parts)
+    w = max(1, -(-cpr * total // (len(parts) * T)))
+    return min(c_pad, -(-w // ndev) * ndev)
+
+
+def shard_order(n_steps: np.ndarray, ndev: int) -> np.ndarray:
+    """Slot order that balances the per-device step sums: the longest
+    clients first, each to the device with the fewest steps that still
+    has a free slot.  One device keeps the given order."""
+    n = len(n_steps)
+    if ndev == 1:
+        return np.arange(n)
+    per = n // ndev
+    bins: list[list[int]] = [[] for _ in range(ndev)]
+    sums = [0] * ndev
+    for i in np.argsort(-np.asarray(n_steps), kind="stable"):
+        d = min((d for d in range(ndev) if len(bins[d]) < per),
+                key=lambda d: sums[d])
+        bins[d].append(int(i))
+        sums[d] += int(n_steps[i])
+    return np.asarray([i for b in bins for i in b])
+
+
+class Plan(NamedTuple):
+    """Which client-step each lane runs at each iteration of the cohort
+    program.  Lanes are split over the devices as the cohort is, so a
+    lane's client is an index into its device's shard of the cohort; an
+    idle lane holds the shard size, one past its last slot."""
+    client: np.ndarray            # (T_cap, W) int32
+    step: np.ndarray              # (T_cap, W) int32 — that client's step
+    n_iter: np.ndarray            # () int32 — iterations that run
+
+
+def schedule(n_steps: np.ndarray, T: int, lanes: int, ndev: int = 1
+             ) -> Plan:
+    """Pack each slot's ``n_steps`` real steps onto ``lanes`` lanes.
+
+    Each device's lanes take its own slots.  At every iteration the clients
+    with the most steps left take one step each, so a client's steps run
+    in order, never two at once, and the device finishes in
+    ``max(max n, ⌈Σ n / lanes per device⌉)`` iterations, the fewest
+    possible.  The table has ``⌈C · T / lanes⌉`` rows, so any cohort fits
+    one compiled shape; ``n_iter`` is the largest device's count.
+    """
+    C = len(n_steps)
+    per, w = C // ndev, lanes // ndev
+    t_cap = -(-C * T // lanes)
+    client = np.full((t_cap, lanes), per, np.int32)
+    step = np.zeros((t_cap, lanes), np.int32)
+    n_iter = 0
+    for d in range(ndev):
+        left = np.array(n_steps[d * per:(d + 1) * per], np.int64)
+        done = np.zeros(per, np.int64)
+        t = 0
+        while left.any():
+            take = np.argsort(-left, kind="stable")[:w]
+            take = take[left[take] > 0]
+            lo = d * w
+            client[t, lo:lo + len(take)] = take
+            step[t, lo:lo + len(take)] = done[take]
+            done[take] += 1
+            left[take] -= 1
+            t += 1
+        n_iter = max(n_iter, t)
+    return Plan(client, step, np.asarray(n_iter, np.int32))
+
+
 def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
-                 pad_clients_to: int, bucket: bool = False) -> Cohort | None:
+                 pad_clients_to: int, bucket: bool = False,
+                 ndev: int = 1) -> Cohort | None:
     """Materialize selected clients' local batches into a padded rectangle
     using the same RNG streams as the sequential oracle.
 
@@ -59,6 +150,9 @@ def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
     the rectangle's T is the next power of two ≥ this cohort's real maximum
     step count — dirichlet-skewed cohorts stop paying for steps nobody runs,
     and the pow-2 snap bounds distinct compiled shapes to log2(T_max).
+
+    ``ndev > 1`` orders the slots by ``shard_order``, so that each device's
+    shard of the cohort holds about as many steps as the others.
     """
     T = fc.max_local_batches * fc.local_epochs
     raw, weights, cids, fallback = [], [], [], []
@@ -94,10 +188,15 @@ def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
         smask.append(np.zeros(T, bool))
         weights.append(0.0)
         nsteps.append(0)
+    order = shard_order(np.asarray(nsteps), ndev)
+    slot = np.argsort(order)
     return Cohort(
-        batches={k: np.stack([s[k] for s in stacked]) for k in stacked[0]},
-        step_mask=np.stack(smask), weights=np.asarray(weights, np.float32),
-        cids=cids, fallback=fallback, n_steps=np.asarray(nsteps))
+        batches={k: np.stack([stacked[i][k] for i in order])
+                 for k in stacked[0]},
+        step_mask=np.stack(smask)[order],
+        weights=np.asarray(weights, np.float32)[order],
+        cids=cids, slots=[int(slot[i]) for i in range(len(cids))],
+        fallback=fallback, n_steps=np.asarray(nsteps)[order])
 
 
 def cohort_mesh():
@@ -111,36 +210,49 @@ def stack_params(trainable: Any, n: int) -> Any:
         lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), trainable)
 
 
+def make_step(model, opt, task: str = "cls"):
+    """One client's local step, shared by the cohort program's lanes and
+    ``make_local_phase``: ``step(base, masks, gate, params, opt_state,
+    batch) → (params', opt_state', grads, loss, metric)``, the oracle's
+    step (``federated/client.py``) op for op."""
+    loss_fn = model.cls_loss if task == "cls" else model.lm_loss
+
+    def step(base, masks, gate, params, opt_state, batch):
+        def f(tr):
+            return loss_fn(base, tr, masks, batch, remat=False)
+
+        (_, (loss, metric)), g = jax.value_and_grad(f, has_aux=True)(params)
+        updates, new_opt = opt.update(g, opt_state, params)
+        if gate is not None:
+            updates = jax.tree.map(
+                lambda u, gt: u * jnp.asarray(gt, u.dtype), updates, gate)
+        new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                  params, updates)
+        return new_params, new_opt, g, loss, metric
+
+    return step
+
+
 def make_local_phase(model, opt, task: str = "cls"):
     """One client's whole local-training phase as a scan over (padded)
-    batches — the shared inner loop of ``make_cohort_fn`` (vmapped per
-    round) and ``fused.make_fused_fn`` (vmapped inside a scan over rounds).
+    batches — the inner loop of ``fused.make_fused_fn`` (vmapped inside a
+    scan over rounds).
 
     ``local_phase(base, params0, masks, gate, bstack, smask) → (params,
     grads, losses, metrics)``; a False ``smask`` step computes and then
     discards, so real steps are structurally identical to the oracle's.
     """
-    loss_fn = model.cls_loss if task == "cls" else model.lm_loss
+    step = make_step(model, opt, task)
 
     def local_phase(base, params0, masks, gate, bstack, smask):
         opt0 = opt.init(params0)
         g0 = jax.tree.map(jnp.zeros_like, params0)
 
-        def step(carry, xs):
+        def scan_step(carry, xs):
             params, opt_state, grads = carry
             batch, live = xs
-
-            def f(tr):
-                return loss_fn(base, tr, masks, batch, remat=False)
-
-            (_, (loss, metric)), g = jax.value_and_grad(
-                f, has_aux=True)(params)
-            updates, new_opt = opt.update(g, opt_state, params)
-            if gate is not None:
-                updates = jax.tree.map(
-                    lambda u, gt: u * jnp.asarray(gt, u.dtype), updates, gate)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                      params, updates)
+            new_params, new_opt, g, loss, metric = step(
+                base, masks, gate, params, opt_state, batch)
 
             def keep(n, o):
                 return jnp.where(live, n, o)
@@ -150,7 +262,7 @@ def make_local_phase(model, opt, task: str = "cls"):
             return carry, (loss, metric)
 
         (params, _, grads), (losses, metrics) = jax.lax.scan(
-            step, (params0, opt0, g0), (bstack, smask))
+            scan_step, (params0, opt0, g0), (bstack, smask))
         return params, grads, losses, metrics
 
     return local_phase
@@ -159,18 +271,49 @@ def make_local_phase(model, opt, task: str = "cls"):
 def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
     """Build the one-dispatch cohort round.
 
-    Returns jitted ``fn(base, stacked, masks, gate, bstacks, smasks, weights)
-    → (params_c, grads_c, losses_c, metrics_c, avg)`` where the ``_c`` outputs
-    carry the cohort axis and ``avg`` is the weight-normalized on-device
-    FedAvg of the final per-client params (weight-0 pad slots drop out).
+    Returns jitted ``fn(base, stacked, masks, gate, bstacks, plan, weights)
+    → (params_c, grads_c, losses_c, metrics_c, avg)`` where ``plan`` is
+    ``schedule``'s table, the ``_c`` outputs carry the cohort axis
+    (``losses_c``/``metrics_c`` are (C, T), zero past a client's steps)
+    and ``avg`` is the weight-normalized on-device FedAvg of the final
+    per-client params (weight-0 pad slots drop out).
+
+    Per device, the loop carries its shard's client-major state and runs
+    ``plan.n_iter`` iterations (a traced count: one compile for every
+    cohort of these shapes).  An iteration gathers each lane's client
+    state and batch, steps all lanes at once, and scatters back; an idle
+    lane's index is out of range, so its write is dropped.
     """
-    local_phase = make_local_phase(model, opt, task)
+    lanes = jax.vmap(make_step(model, opt, task),
+                     in_axes=(None, None, None, 0, 0, 0))
     mesh = mesh if mesh is not None else cohort_mesh()
 
-    def cohort_round(base, stacked, masks, gate, bstacks, smasks, weights):
-        params_c, grads_c, losses_c, metrics_c = jax.vmap(
-            local_phase, in_axes=(None, 0, None, None, 0, 0))(
-            base, stacked, masks, gate, bstacks, smasks)
+    def cohort_round(base, stacked, masks, gate, bstacks, plan, weights):
+        C = weights.shape[0]
+        T = jax.tree.leaves(bstacks)[0].shape[1]
+        state = (stacked, jax.vmap(opt.init)(stacked),
+                 jax.tree.map(jnp.zeros_like, stacked))
+        zeros = jnp.zeros((C, T), jnp.float32)
+
+        def body(t, carry):
+            state, losses, metrics = carry
+            cl, st = plan.client[t], plan.step[t]
+            batch = jax.tree.map(lambda b: b.at[cl, st].get(mode="clip"),
+                                 bstacks)
+            params, opt_state = jax.tree.map(
+                lambda x: x.at[cl].get(mode="clip"), state[:2])
+            p, o, g, loss, metric = lanes(base, masks, gate, params,
+                                          opt_state, batch)
+            state = jax.tree.map(lambda x, v: x.at[cl].set(v, mode="drop"),
+                                 state, (p, o, g))
+            losses = losses.at[cl, st].set(loss.astype(jnp.float32),
+                                           mode="drop")
+            metrics = metrics.at[cl, st].set(metric.astype(jnp.float32),
+                                             mode="drop")
+            return state, losses, metrics
+
+        (params_c, _, grads_c), losses_c, metrics_c = jax.lax.fori_loop(
+            0, plan.n_iter, body, (state, zeros, zeros))
         part = jax.tree.map(
             lambda p: jnp.tensordot(weights, p.astype(jnp.float32),
                                     axes=(0, 0)), params_c)
@@ -181,9 +324,11 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
         return params_c, grads_c, losses_c, metrics_c, avg
 
     cspec = P("clients")
+    lspec = P(None, "clients")
     fn = jax.shard_map(
         cohort_round, mesh=mesh,
-        in_specs=(P(), cspec, P(), P(), cspec, cspec, cspec),
+        in_specs=(P(), cspec, P(), P(), cspec, Plan(lspec, lspec, P()),
+                  cspec),
         out_specs=(cspec, cspec, cspec, cspec, P()),
         check_vma=False)
     return jax.jit(fn)

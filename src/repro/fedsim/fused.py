@@ -90,8 +90,9 @@ def make_fused_fn(model, opt, task: str = "cls", mesh=None):
     ``trainable`` is the replicated carry — donated, so the block trains in
     place — and ``losses``/``metrics`` come back ``(K, C, T)``.
 
-    Round structure matches ``cohort.make_cohort_fn`` op for op: vmap of the
-    shared local phase, weighted tensordot, psum over the ``"clients"``
+    Each round vmaps the local phase (``cohort.make_local_phase``, built on
+    the cohort program's step) over the padded rectangle, then takes the
+    cohort program's weighted tensordot and psum over the ``"clients"``
     axis.  The only addition is the ``wtot > 0`` guard so an all-dropped or
     block-padding round passes the carry through unchanged.
     """
@@ -237,7 +238,7 @@ def run_fused(model, strategy, parts, train, test, fc,
             losses = []
             met = OBS.get_metrics()
             if cohort is not None:
-                for i, cid in enumerate(cohort.cids):
+                for i, cid in zip(cohort.slots, cohort.cids):
                     csp = history.begin_client(cid)
                     sm = cohort.step_mask[i]
                     loss_i = float(np.mean(lc[j][i][sm]))
@@ -253,7 +254,7 @@ def run_fused(model, strategy, parts, train, test, fc,
 
             costs = []
             if cohort is not None:
-                idx_of = {cid: i for i, cid in enumerate(cohort.cids)}
+                idx_of = dict(zip(cohort.cids, cohort.slots))
                 for k, cid in enumerate(sels[j]):
                     if dropss[j][k]:
                         continue

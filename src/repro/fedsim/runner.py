@@ -4,9 +4,10 @@ Two execution modes behind ``FedConfig.runner`` (the sequential oracle stays
 in federated/server.py):
 
   cohort  barrier-synchronous rounds whose local phase is ONE
-          vmap+scan+shard_map dispatch (fedsim/cohort.py) with on-device psum
-          FedAvg; dropout/straggler injection and a simulated wall clock from
-          the per-device-class transport links.
+          shard_map dispatch (fedsim/cohort.py: real client-steps packed
+          onto lanes) with on-device psum FedAvg; dropout/straggler
+          injection and a simulated wall clock from the per-device-class
+          transport links.
   async   FedBuff-style buffered aggregation [Nguyen et al. 2022]: clients
           train against the global version they were dispatched with; the
           server aggregates every K arrivals with size·(1+staleness)^-α
@@ -96,6 +97,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
     ndev = len(jax.devices())
     cpr = min(fc.clients_per_round, len(parts))
     c_pad = -(-cpr // ndev) * ndev                        # shardable cohort
+    lanes = CH.lane_count(parts, fc, c_pad, ndev)
 
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
@@ -139,11 +141,13 @@ def run_cohort(model, strategy, parts, train, test, fc,
 
         # ---- local phase: one dispatch for the whole cohort --------------
         cohort = CH.build_cohort(train, parts, active, fc, rnd, c_pad,
-                                 bucket=fc.rebucket)
+                                 bucket=fc.rebucket, ndev=ndev)
         pc = gc = lc = mc = avg = None
         cohort_idx = {}
         if cohort is not None:
             stacked = CH.stack_params(bc, len(cohort.weights))
+            plan = CH.schedule(cohort.n_steps, cohort.step_mask.shape[1],
+                               lanes, ndev)
         psp.end()
         if cohort is not None:
             # dispatch span keyed by shape signature: any jax compile fired
@@ -155,14 +159,16 @@ def run_cohort(model, strategy, parts, train, test, fc,
             if tr.has_readers:
                 from repro.obs import profile as PROF
                 sig["sig"] = PROF.shape_signature(
-                    stacked, cohort.batches, cohort.step_mask,
-                    cohort.weights)
+                    stacked, cohort.batches, plan, cohort.weights)
             dsp = tr.begin("cohort_dispatch", kind="dispatch", **sig)
+            # fill share = steps / slots: real client-steps over lane-steps
+            dsp.count(steps=int(cohort.n_steps.sum()),
+                      slots=lanes * int(plan.n_iter))
             pc, gc, lc, mc, avg = cohort_fn(
-                base, stacked, masks, gate, cohort.batches,
-                cohort.step_mask, cohort.weights)
+                base, stacked, masks, gate, cohort.batches, plan,
+                cohort.weights)
             dsp.end()
-            cohort_idx = {cid: i for i, cid in enumerate(cohort.cids)}
+            cohort_idx = dict(zip(cohort.cids, cohort.slots))
             # ONE batched device→host pull for everything the host path
             # reads — cohort params, broadcast ref, (optional) grads, and
             # the loss/metric stacks; the per-client params/deltas below

@@ -19,6 +19,7 @@ from repro.federated.baselines import all_strategies
 from repro.federated.partition import dirichlet_partition
 from repro.federated.server import FedConfig, run_federated
 from repro.fedsim import transport as T
+from repro.fedsim import cohort as CH
 from repro.fedsim.cohort import client_batch_rng
 from repro.models import Model
 
@@ -41,9 +42,9 @@ def _run(setup, runner, strategy="fedara", **fc_kw):
         strat.warmup_rounds = 1
         strat.final_rounds_frac = 0.34
     model = Model(cfg, peft=strat.peft, unroll=True)
-    fc = FedConfig(rounds=rounds, clients_per_round=3, batch_size=16,
-                   max_local_batches=3, eval_every=rounds, lr=3e-3,
-                   runner=runner, **fc_kw)
+    fc = FedConfig(**{"clients_per_round": 3, "max_local_batches": 3,
+                      **fc_kw}, rounds=rounds, batch_size=16,
+                   eval_every=rounds, lr=3e-3, runner=runner)
     return run_federated(model, strat, parts, train, test, fc)
 
 
@@ -68,6 +69,51 @@ def test_cohort_matches_sequential_oracle(setup):
     np.testing.assert_allclose(h_seq["final_acc"], h_coh["final_acc"],
                                atol=0.02)
     assert h_coh["sim_time_s"] > 0.0
+
+
+def test_scheduled_cohort_matches_sequential_oracle(setup):
+    """Uneven clients on fewer lanes than slots: the cohort program packs
+    the real client-steps (fewer lane-steps than the padded rectangle's
+    C × T) and still matches the oracle's losses, masks and bytes."""
+    from repro import obs
+    kw = dict(clients_per_round=5, max_local_batches=4)
+    h_seq = _run(setup, "seq", **kw)
+    tr = obs.configure(None, health=False, profile=False)
+    try:
+        h_coh = _run(setup, "cohort", **kw)
+        evs = tr.events()
+    finally:
+        obs.disable()
+    spans = [e for e in evs if e.get("type") == "span"]
+    rounds = {e["id"] for e in spans if e["name"] == "round"}
+    cnt = {e["parent"]: e["attrs"] for e in spans
+           if e["name"] == "cohort_dispatch"}
+    assert set(cnt) == rounds and len(rounds) == 3
+    parts, fc = setup[3], FedConfig(batch_size=16, **kw)
+    lanes = CH.lane_count(parts, fc, 5, 1)
+    assert lanes < 5
+    by_id = {e["id"]: e for e in spans}
+
+    def round_of(e):
+        while e["id"] not in rounds:
+            e = by_id[e["parent"]]
+        return e["id"]
+    for rid, c in cnt.items():
+        real = sum(CH.cohort_steps(len(parts[e["attrs"]["cid"]]), fc)
+                   for e in spans if e["name"] == "client"
+                   and round_of(e) == rid)
+        assert c["steps"] == real > 0
+        assert c["slots"] % lanes == 0
+        assert c["steps"] <= c["slots"] < 5 * 4     # fewer than C × T
+    for a, b in zip(h_seq["rounds"], h_coh["rounds"]):
+        assert a.down_bytes == b.down_bytes
+        assert a.up_bytes == b.up_bytes
+        assert a.live_ranks == b.live_ranks
+        assert a.dead_modules == b.dead_modules
+        np.testing.assert_allclose(a.loss, b.loss, rtol=2e-4, atol=2e-4)
+    for x, y in zip(jax.tree.leaves(h_seq["masks"]),
+                    jax.tree.leaves(h_coh["masks"])):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_cohort_outputs_are_freed_before_the_next_dispatch(setup,
@@ -360,12 +406,12 @@ SCRIPT = textwrap.dedent("""
     test = make_classification(100, 10, cfg.vocab_size, 16, seed=2)
     parts = iid_partition(train.labels, 8, seed=0)
 
-    def go(runner):
+    def go(runner, clients_per_round=4, max_local_batches=2):
         strat = all_strategies(rounds=2)["fedlora"]
         model = Model(cfg, peft=strat.peft, unroll=True)
-        fc = FedConfig(rounds=2, clients_per_round=4, batch_size=16,
-                       max_local_batches=2, eval_every=4, lr=3e-3,
-                       runner=runner)
+        fc = FedConfig(rounds=2, clients_per_round=clients_per_round,
+                       batch_size=16, max_local_batches=max_local_batches,
+                       eval_every=4, lr=3e-3, runner=runner)
         return run_federated(model, strat, parts, train, test, fc)
 
     import jax
@@ -374,6 +420,23 @@ SCRIPT = textwrap.dedent("""
     for a, b in zip(h_seq["rounds"], h_coh["rounds"]):
         np.testing.assert_allclose(a.loss, b.loss, rtol=2e-4, atol=2e-4)
         assert a.down_bytes == b.down_bytes
+
+    # uneven clients, 16 a round: 8 lanes for 16 slots, one per device,
+    # and the slots ordered so the devices' step sums balance
+    from repro.fedsim import cohort as CH
+    train = make_classification(600, 10, cfg.vocab_size, 16, seed=3)
+    sizes = [64, 16, 16, 32, 16, 48, 16, 16, 32, 5,
+             64, 16, 16, 16, 32, 16, 16, 48, 16, 16]
+    order = np.random.default_rng(0).permutation(len(train.labels))
+    parts = np.split(order[:sum(sizes)], np.cumsum(sizes)[:-1])
+    fc = FedConfig(clients_per_round=16, batch_size=16, max_local_batches=4)
+    assert CH.lane_count(parts, fc, 16, 8) == 8
+    kw = dict(clients_per_round=16, max_local_batches=4)
+    h_seq, h_coh = go("seq", **kw), go("cohort", **kw)
+    for a, b in zip(h_seq["rounds"], h_coh["rounds"]):
+        np.testing.assert_allclose(a.loss, b.loss, rtol=2e-4, atol=2e-4)
+        assert a.down_bytes == b.down_bytes
+        assert a.up_bytes == b.up_bytes
     print("SHARDED_COHORT_OK")
 """)
 

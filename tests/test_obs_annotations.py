@@ -272,3 +272,21 @@ def test_cohort_stage_counters(cohort_trace):
                    for e in evs)
     assert all("sig" not in e["attrs"] for e in spans
                if e["name"] == "cohort_dispatch")
+
+
+def test_cohort_dispatch_counts_steps_and_slots(cohort_trace):
+    """``steps`` is the round's real client-steps, ``slots`` the lane-steps
+    the cohort program ran: a whole number of iterations on every lane."""
+    from repro.fedsim import cohort as CH
+    evs, host, parts, fc = cohort_trace
+    lanes = CH.lane_count(parts, fc, fc.clients_per_round, 1)
+    rounds = _by_round(host)
+    for rnd, inside in rounds.items():
+        (st,) = [st for n, _, _, st in inside if n == "cohort_dispatch"]
+        real = sum(CH.cohort_steps(len(parts[st_c["cid"]]), fc)
+                   for n, _, _, st_c in inside if n == "client")
+        assert st["steps"] == real > 0, rnd
+        assert st["slots"] % lanes == 0
+        assert st["steps"] <= st["slots"]
+        assert st["slots"] <= -(-fc.clients_per_round
+                                * fc.max_local_batches // lanes) * lanes
