@@ -400,7 +400,7 @@ def _cohort_split(cfg, seed: int, parts, train, cpr: int) -> str:
     base, _ = SV.pin_params(base, sharding=rep)
     bc, masks = SV.pin_params(trainable, masks, sharding=rep)
     fn = CH.make_cohort_fn(model, opt, fc.task, mesh=mesh)
-    pc, _, lc, _, _ = fn(base, CH.stack_params(bc, len(cohort.weights)),
+    pc, _, lc, _, _, _ = fn(base, CH.stack_params(bc, len(cohort.weights)),
                          masks, strat.optimizer_gate(bc, None),
                          cohort.batches, plan, cohort.weights)
     leaf = jax.tree.leaves(pc)[0]

@@ -31,6 +31,15 @@ class ArchConfig:
     head_dim: int = 0                  # 0 → d_model // n_heads
     layer_pattern: tuple[BlockKind, ...] = ()   # len == n_layers; () → all "attn"
 
+    # multi-head latent attention (MLA: DeepSeek-V3, Kimi K2), on when
+    # kv_lora_rank > 0: q = RMSNorm(x W_qa) W_qb, [c_kv | k_rope] = x W_kva,
+    # [k_nope | v] = RMSNorm(c_kv) W_kvb; k_rope is shared by the heads
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # attention features
     sliding_window: int = 0            # window for "local" blocks
     attn_softcap: float = 0.0          # gemma2 logit soft-capping
@@ -40,12 +49,33 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     pos_emb: str = "rope"              # rope | learned | sinusoidal | none
     max_position: int = 1 << 20
+    # YaRN RoPE scaling (factor 0 → plain RoPE); the DeepSeek-V3 form
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MoE
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.5
     router_aux_coef: float = 0.01
+    # "softmax": capacity-bounded dispatch with a load-balance loss;
+    # "sigmoid": DeepSeek-V3's dropless routing — top_k of sigmoid scores
+    # plus a frozen correction bias, weights normalised and scaled by
+    # routed_scale, no auxiliary loss (models/moe.py)
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    n_shared_experts: int = 0          # always-on experts of width d_ff each
+    first_k_dense: int = 0             # leading dense layers of a moe family
+    d_ff_dense: int = 0                # their FFN width (0 → d_ff)
+    # the chip's share under expert parallelism: this chip holds experts
+    # [expert_shard · experts_held, (expert_shard + 1) · experts_held) of
+    # the n_experts the router scores (0 → all of them)
+    experts_held: int = 0
+    expert_shard: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -77,6 +107,9 @@ class ArchConfig:
     # dtypes
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
+    # federated steps recompute each block in the backward pass (saving
+    # only block inputs): for widths whose activations would not fit
+    train_remat: bool = False
 
     # PEFT policy (the paper's technique)
     adapter_targets: tuple[str, ...] = ("wq", "wk", "wv", "wo", "w1", "w3", "w2")
@@ -92,11 +125,21 @@ class ArchConfig:
                 kind = "moe"
             elif self.family == "ssm":
                 kind = "mamba"
-            object.__setattr__(self, "layer_pattern", (kind,) * self.n_layers)
+            k = self.first_k_dense if kind == "moe" else 0
+            object.__setattr__(self, "layer_pattern",
+                               ("attn",) * k + (kind,) * (self.n_layers - k))
         if len(self.layer_pattern) != self.n_layers:
             raise ValueError(
                 f"{self.name}: layer_pattern has {len(self.layer_pattern)} "
                 f"entries for n_layers={self.n_layers}")
+        if self.experts_held and (
+                self.n_experts % self.experts_held
+                or not 0 <= self.expert_shard
+                < self.n_experts // self.experts_held):
+            raise ValueError(
+                f"{self.name}: {self.experts_held} held experts (share "
+                f"{self.expert_shard}) do not divide {self.n_experts} "
+                f"experts into whole shares")
 
     # ---- derived -----------------------------------------------------------
     @property
@@ -106,6 +149,16 @@ class ArchConfig:
     @property
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first expert id, count) of the experts this chip holds."""
+        n = self.experts_held or self.n_experts
+        return self.expert_shard * n, n
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def d_inner(self) -> int:          # mamba2 inner width

@@ -340,7 +340,7 @@ def _run_stage1(model, strategy, base, trainable, parts, train, fc, opt, rng,
             n_b = 0
             for bt in gen:
                 jb = {k: jnp.asarray(v) for k, v in bt.items()}
-                params_k, opt_t, _, gb, _, _ = s1_step(
+                params_k, opt_t, _, gb, _, _, _ = s1_step(
                     bk, params_k, opt_t, masks, None, jb)
                 bk, opt_b = s1_update(bk, opt_b, gb, s1_gate)
                 n_b += 1

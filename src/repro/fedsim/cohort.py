@@ -200,8 +200,12 @@ def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
 
 
 def cohort_mesh():
-    """1-D mesh over every local device; the cohort axis shards across it."""
-    return jax.make_mesh((len(jax.devices()),), ("clients",))
+    """1-D mesh over every local device; the cohort axis shards across it.
+    The axis is ``Auto``: values placed on it outside ``shard_map`` (the
+    pinned broadcast state) carry no sharding type, which grouped products
+    (``jax.lax.ragged_dot``, models/moe.py) have no rule for."""
+    return jax.make_mesh((len(jax.devices()),), ("clients",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def stack_params(trainable: Any, n: int) -> Any:
@@ -213,22 +217,24 @@ def stack_params(trainable: Any, n: int) -> Any:
 def make_step(model, opt, task: str = "cls"):
     """One client's local step, shared by the cohort program's lanes and
     ``make_local_phase``: ``step(base, masks, gate, params, opt_state,
-    batch) → (params', opt_state', grads, loss, metric)``, the oracle's
-    step (``federated/client.py``) op for op."""
+    batch) → (params', opt_state', grads, loss, metric, counts)``, the
+    oracle's step (``federated/client.py``) op for op."""
     loss_fn = model.cls_loss if task == "cls" else model.lm_loss
+    remat = model.cfg.train_remat
 
     def step(base, masks, gate, params, opt_state, batch):
         def f(tr):
-            return loss_fn(base, tr, masks, batch, remat=False)
+            return loss_fn(base, tr, masks, batch, remat=remat)
 
-        (_, (loss, metric)), g = jax.value_and_grad(f, has_aux=True)(params)
+        (_, (loss, metric, counts)), g = jax.value_and_grad(
+            f, has_aux=True)(params)
         updates, new_opt = opt.update(g, opt_state, params)
         if gate is not None:
             updates = jax.tree.map(
                 lambda u, gt: u * jnp.asarray(gt, u.dtype), updates, gate)
         new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
                                   params, updates)
-        return new_params, new_opt, g, loss, metric
+        return new_params, new_opt, g, loss, metric, counts
 
     return step
 
@@ -251,7 +257,7 @@ def make_local_phase(model, opt, task: str = "cls"):
         def scan_step(carry, xs):
             params, opt_state, grads = carry
             batch, live = xs
-            new_params, new_opt, g, loss, metric = step(
+            new_params, new_opt, g, loss, metric, _ = step(
                 base, masks, gate, params, opt_state, batch)
 
             def keep(n, o):
@@ -272,11 +278,14 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
     """Build the one-dispatch cohort round.
 
     Returns jitted ``fn(base, stacked, masks, gate, bstacks, plan, weights)
-    → (params_c, grads_c, losses_c, metrics_c, avg)`` where ``plan`` is
-    ``schedule``'s table, the ``_c`` outputs carry the cohort axis
-    (``losses_c``/``metrics_c`` are (C, T), zero past a client's steps)
-    and ``avg`` is the weight-normalized on-device FedAvg of the final
-    per-client params (weight-0 pad slots drop out).
+    → (params_c, grads_c, losses_c, metrics_c, avg, counts)`` where
+    ``plan`` is ``schedule``'s table, the ``_c`` outputs carry the cohort
+    axis (``losses_c``/``metrics_c`` are (C, T), zero past a client's
+    steps), ``avg`` is the weight-normalized on-device FedAvg of the final
+    per-client params (weight-0 pad slots drop out) and ``counts`` sums the
+    model's step counts (``cls_loss``'s; {} for most models) over every
+    lane the round ran, idle lanes included: their work is done all the
+    same, and ``steps / slots`` says how much of it was idle.
 
     Per device, the loop carries its shard's client-major state and runs
     ``plan.n_iter`` iterations (a traced count: one compile for every
@@ -294,26 +303,29 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
         state = (stacked, jax.vmap(opt.init)(stacked),
                  jax.tree.map(jnp.zeros_like, stacked))
         zeros = jnp.zeros((C, T), jnp.float32)
+        counts0 = {k: jnp.float32(0.0) for k in model.count_keys}
 
         def body(t, carry):
-            state, losses, metrics = carry
+            state, losses, metrics, counts = carry
             cl, st = plan.client[t], plan.step[t]
             batch = jax.tree.map(lambda b: b.at[cl, st].get(mode="clip"),
                                  bstacks)
             params, opt_state = jax.tree.map(
                 lambda x: x.at[cl].get(mode="clip"), state[:2])
-            p, o, g, loss, metric = lanes(base, masks, gate, params,
-                                          opt_state, batch)
+            p, o, g, loss, metric, c = lanes(base, masks, gate, params,
+                                             opt_state, batch)
+            counts = {k: v + c[k].sum() for k, v in counts.items()}
             state = jax.tree.map(lambda x, v: x.at[cl].set(v, mode="drop"),
                                  state, (p, o, g))
             losses = losses.at[cl, st].set(loss.astype(jnp.float32),
                                            mode="drop")
             metrics = metrics.at[cl, st].set(metric.astype(jnp.float32),
                                              mode="drop")
-            return state, losses, metrics
+            return state, losses, metrics, counts
 
-        (params_c, _, grads_c), losses_c, metrics_c = jax.lax.fori_loop(
-            0, plan.n_iter, body, (state, zeros, zeros))
+        (params_c, _, grads_c), losses_c, metrics_c, counts = \
+            jax.lax.fori_loop(0, plan.n_iter, body,
+                              (state, zeros, zeros, counts0))
         part = jax.tree.map(
             lambda p: jnp.tensordot(weights, p.astype(jnp.float32),
                                     axes=(0, 0)), params_c)
@@ -321,7 +333,8 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
         wtot = jax.lax.psum(weights.sum(), "clients")
         avg = jax.tree.map(lambda s, p: (s / wtot).astype(p.dtype),
                            tot, params_c)
-        return params_c, grads_c, losses_c, metrics_c, avg
+        return (params_c, grads_c, losses_c, metrics_c, avg,
+                jax.lax.psum(counts, "clients"))
 
     cspec = P("clients")
     lspec = P(None, "clients")
@@ -329,7 +342,7 @@ def make_cohort_fn(model, opt, task: str = "cls", mesh=None):
         cohort_round, mesh=mesh,
         in_specs=(P(), cspec, P(), P(), cspec, Plan(lspec, lspec, P()),
                   cspec),
-        out_specs=(cspec, cspec, cspec, cspec, P()),
+        out_specs=(cspec, cspec, cspec, cspec, P(), P()),
         check_vma=False)
     return jax.jit(fn)
 

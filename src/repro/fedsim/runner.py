@@ -164,7 +164,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
             # fill share = steps / slots: real client-steps over lane-steps
             dsp.count(steps=int(cohort.n_steps.sum()),
                       slots=lanes * int(plan.n_iter))
-            pc, gc, lc, mc, avg = cohort_fn(
+            pc, gc, lc, mc, avg, counts = cohort_fn(
                 base, stacked, masks, gate, cohort.batches, plan,
                 cohort.weights)
             dsp.end()
@@ -176,11 +176,14 @@ def run_cohort(model, strategy, parts, train, test, fc,
             # the client loop.
             pull = (pc, bc,
                     gc if strategy.uses_masks() and gc is not None else None,
-                    lc, mc)
+                    lc, mc, counts)
             ksp = tr.begin("cohort.pull", kind="stage")
             if tr.enabled:
                 ksp.count(nbytes=sum(x.nbytes for x in jax.tree.leaves(pull)))
-            pc_host, bc_host, gc_host, lc, mc = jax.device_get(pull)
+            pc_host, bc_host, gc_host, lc, mc, counts = jax.device_get(pull)
+            # the model's counts: held experts' pairs and estimated rows,
+            # whose ratio estimates the expert fill share
+            ksp.count(**{k: round(float(v)) for k, v in counts.items()})
             ksp.end()
             del pull    # or it holds this round's outputs through the next
 
@@ -221,7 +224,8 @@ def run_cohort(model, strategy, parts, train, test, fc,
                     step_fn, base, bc, masks, gate, opt, gen)
                 delta_k = PL.delta_tree(params_k, bc)
                 w = float(len(parts[cid]))
-                fsp.count(steps=m["n_batches"], rows=len(idx))
+                fsp.count(steps=m["n_batches"], rows=len(idx),
+                          **{k: round(v) for k, v in m["counts"].items()})
                 fsp.end()
             lm = None
             if strategy.uses_masks():

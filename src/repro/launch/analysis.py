@@ -139,19 +139,36 @@ def cost_terms(compiled, n_chips: int):
 
 
 def active_params(cfg) -> tuple[int, int]:
-    """(total, active) parameter counts from the config (analytic)."""
+    """(total, active) parameter counts from the config (analytic): the
+    experts this chip holds (all, unless the config holds a share), and
+    of them the top_k a token expects (top_k · held / n_experts); shared
+    experts, a MoE model's dense layers at their own width, and MLA's
+    five projections count as published."""
     d, v = cfg.d_model, cfg.vocab_size
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    per_attn = d * hd * (h + 2 * kv) + h * hd * d
+    if cfg.mla:
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        qr, kr, vd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.v_head_dim
+        per_attn = (d * qr + qr * h * (nope + rope) + d * (kr + rope)
+                    + kr * h * (nope + vd) + h * vd * d)
+    else:
+        per_attn = d * hd * (h + 2 * kv) + h * hd * d
     glu_mult = 3 if cfg.glu else 2
     per_mlp = glu_mult * d * cfg.d_ff
-    per_moe = cfg.n_experts * per_mlp + d * cfg.n_experts
-    per_moe_active = cfg.top_k * per_mlp + d * cfg.n_experts
+    per_dense = glu_mult * d * (cfg.d_ff_dense or cfg.d_ff)
+    _, held = cfg.held
+    shared = cfg.n_shared_experts * per_mlp + d * cfg.n_experts
+    per_moe = held * per_mlp + shared
+    per_moe_active = (round(cfg.top_k * held / cfg.n_experts * per_mlp)
+                      + shared) if cfg.n_experts else 0
     d_in_proj = 2 * cfg.d_inner + 2 * cfg.ssm_state + \
         (cfg.d_inner // cfg.ssm_head_dim if cfg.ssm_state else 0)
     per_mamba = d * d_in_proj + cfg.d_inner * d if cfg.ssm_state else 0
 
     total = active = v * d
+    if not cfg.tie_embeddings and not cfg.n_classes:    # the LM head
+        total += v * d
+        active += v * d
     pattern = cfg.layer_pattern
     if cfg.is_encoder_decoder:
         pattern = pattern + ("attn",) * cfg.n_encoder_layers
@@ -164,8 +181,8 @@ def active_params(cfg) -> tuple[int, int]:
             active += per_attn + per_moe_active
         else:
             extra = per_attn if kind != "dec" else 2 * per_attn
-            total += extra + per_mlp
-            active += extra + per_mlp
+            total += extra + per_dense
+            active += extra + per_dense
     return total, active
 
 

@@ -33,7 +33,7 @@ def make_train_step(model, opt: OPT.Optimizer, ctx: Ctx, task: str = "lm"):
     def train_step(base, trainable, opt_state, masks, batch):
         def f(tr):
             return loss_fn(base, tr, masks, batch, ctx=ctx)
-        (_, (loss, metric)), grads = jax.value_and_grad(f, has_aux=True)(
+        (_, (loss, metric, _)), grads = jax.value_and_grad(f, has_aux=True)(
             trainable)
         updates, opt_state = opt.update(grads, opt_state, trainable)
         trainable = jax.tree.map(lambda p, u: p + u.astype(p.dtype),

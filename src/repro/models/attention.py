@@ -111,9 +111,11 @@ def _chunked(q, k, v, scale, softcap, window, chunk_q, chunk_kv,
              causal=True):
     """Online-softmax attention, O(chunk²) live memory.
 
-    q: (B, Sq, KV, G, hd); k/v: (B, Sk, KV, hd), Sq == Sk (train/prefill).
+    q: (B, Sq, KV, G, hd); k: (B, Sk, KV, hd); v: (B, Sk, KV, hv), Sq == Sk
+    (train/prefill).
     """
     b, sq, kv, g, hd = q.shape
+    hv = v.shape[-1]
     sk = k.shape[1]
     nq = sq // chunk_q
     qs = q.reshape(b, nq, chunk_q, kv, g, hd)
@@ -140,11 +142,11 @@ def _chunked(q, k, v, scale, softcap, window, chunk_q, chunk_kv,
             return _direct(qc, kc, vc, m[None, None, None], scale, softcap)
 
         outs = jax.lax.map(per_chunk, (jnp.arange(nq), qs.swapaxes(0, 1)))
-        return outs.swapaxes(0, 1).reshape(b, sq, kv, g, hd)
+        return outs.swapaxes(0, 1).reshape(b, sq, kv, g, hv)
 
     nk = sk // chunk_kv
     ks = k.reshape(b, nk, chunk_kv, kv, hd)
-    vs = v.reshape(b, nk, chunk_kv, kv, hd)
+    vs = v.reshape(b, nk, chunk_kv, kv, hv)
 
     def q_body(args):
         i, qc = args
@@ -168,13 +170,13 @@ def _chunked(q, k, v, scale, softcap, window, chunk_q, chunk_kv,
 
         m0 = jnp.full((b, kv, g, chunk_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kv, g, chunk_q), jnp.float32)
-        a0 = jnp.zeros((b, kv, g, chunk_q, hd), jnp.float32)
+        a0 = jnp.zeros((b, kv, g, chunk_q, hv), jnp.float32)
         (_, l_f, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), jnp.arange(nk))
         o = acc / jnp.maximum(l_f, 1e-30)[..., None]
         return o.transpose(0, 3, 1, 2, 4).astype(q.dtype)   # (b,cq,kv,g,hd)
 
     outs = jax.lax.map(q_body, (jnp.arange(nq), qs.swapaxes(0, 1)))
-    return outs.swapaxes(0, 1).reshape(b, sq, kv, g, hd)
+    return outs.swapaxes(0, 1).reshape(b, sq, kv, g, hv)
 
 
 # ------------------------------------------------------------- public ops ---

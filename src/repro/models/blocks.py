@@ -1,5 +1,7 @@
 """Transformer blocks: (local/global) attention+MLP, attention+MoE, Mamba2,
 shared-attention (zamba2), encoder and decoder (cross-attn) variants.
+Self-attention is multi-head latent attention (models/mla.py) where the
+config sets ``kv_lora_rank``; a MoE model's dense blocks take ``d_ff_dense``.
 
 Block kinds:
   attn        pre-norm GQA attention + FFN
@@ -14,11 +16,10 @@ Block kinds:
 
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from repro.core import adapters as AD
 from repro.models import attention as ATT
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import mlp as MLP
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
@@ -30,11 +31,16 @@ def is_moe(kind: str) -> bool:
     return kind in ("moe", "local_moe")
 
 
+def merge_stats(a: dict, b: dict) -> dict:
+    """Sum two dicts of per-block statistics key by key."""
+    return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in {**a, **b}}
+
+
 def block_meta(cfg, kind: str) -> dict:
     if kind == "mamba":
         return {"ln1": L.norm_meta(cfg), "ssm": SSM.ssm_meta(cfg)}
     m = {"ln1": L.norm_meta(cfg),
-         "attn": ATT.attn_meta(cfg),
+         "attn": MLA.mla_meta(cfg) if cfg.mla else ATT.attn_meta(cfg),
          "ln2": L.norm_meta(cfg)}
     if kind == "dec":
         m["lnx"] = L.norm_meta(cfg)
@@ -42,7 +48,7 @@ def block_meta(cfg, kind: str) -> dict:
     if is_moe(kind):
         m["moe"] = MOE.moe_meta(cfg)
     else:
-        m["mlp"] = MLP.mlp_meta(cfg)
+        m["mlp"] = MLP.mlp_meta(cfg, cfg.d_ff_dense)
     if cfg.post_block_norm:
         m["pn1"] = L.norm_meta(cfg)
         m["pn2"] = L.norm_meta(cfg)
@@ -62,13 +68,14 @@ def block_adapter_meta(cfg, kind: str, peft: str) -> dict:
     assert peft in LORA_KINDS, peft
     if kind == "mamba":
         return {"ssm": SSM.ssm_adapter_meta(cfg, peft)}
-    out = {"attn": ATT.attn_adapter_meta(cfg, peft)}
+    out = {"attn": MLA.mla_adapter_meta(cfg, peft) if cfg.mla
+           else ATT.attn_adapter_meta(cfg, peft)}
     if kind == "dec":
         out["xattn"] = ATT.attn_adapter_meta(cfg, peft)
     if is_moe(kind):
         out["moe"] = MOE.moe_adapter_meta(cfg, peft)
     else:
-        out["mlp"] = MLP.mlp_adapter_meta(cfg, peft)
+        out["mlp"] = MLP.mlp_adapter_meta(cfg, peft, cfg.d_ff_dense)
     return {k: v for k, v in out.items() if v}
 
 
@@ -78,6 +85,8 @@ def block_cache_meta(cfg, kind: str, batch: int, seq: int,
         return None
     if kind == "mamba":
         return {"ssm_cache": SSM.ssm_cache_meta(cfg, batch)}
+    if cfg.mla:
+        return {"attn_cache": MLA.cache_meta(cfg, batch, seq)}
     window = cfg.sliding_window if (
         kind.startswith("local")
         or (kind == "shared_attn" and cfg.sliding_window)) else 0
@@ -89,11 +98,12 @@ def block_cache_meta(cfg, kind: str, batch: int, seq: int,
 
 def block_apply(p: dict, x, cfg, kind: str, *, mode: str = "train",
                 ad=None, masks=None, cache=None, ctx=None, enc_out=None):
-    """Returns (x, aux_loss, new_cache)."""
+    """Returns (x, stats, new_cache): ``stats`` holds the softmax router's
+    load-balance loss (``balance``) or the held experts' counts."""
     ad = ad or {}
     masks = masks or {}
     cache = cache or {}
-    aux = jnp.float32(0.0)
+    stats = {}
     new_cache = {}
 
     if kind == "mamba":
@@ -106,15 +116,20 @@ def block_apply(p: dict, x, cfg, kind: str, *, mode: str = "train",
         x = x + h
         if "post_mlp" in ad:
             x = AD.apply_bottleneck(x, ad["post_mlp"])
-        return x, aux, (new_cache or None)
+        return x, stats, (new_cache or None)
 
     window = cfg.sliding_window if kind.startswith("local") else 0
     causal = (kind != "enc") and cfg.causal
-    h, nc = ATT.attention(p["attn"], L.norm_apply(p["ln1"], x, cfg), cfg,
-                          mode=mode, ad=ad.get("attn"),
-                          masks=masks.get("attn"), window=window,
-                          cache=cache.get("attn_cache"), causal=causal,
-                          ctx=ctx)
+    if cfg.mla:
+        h, nc = MLA.mla(p["attn"], L.norm_apply(p["ln1"], x, cfg), cfg,
+                        mode=mode, ad=ad.get("attn"), masks=masks.get("attn"),
+                        cache=cache.get("attn_cache"), causal=causal, ctx=ctx)
+    else:
+        h, nc = ATT.attention(p["attn"], L.norm_apply(p["ln1"], x, cfg), cfg,
+                              mode=mode, ad=ad.get("attn"),
+                              masks=masks.get("attn"), window=window,
+                              cache=cache.get("attn_cache"), causal=causal,
+                              ctx=ctx)
     if nc is not None:
         new_cache["attn_cache"] = nc
     if "pn1" in p:
@@ -134,9 +149,13 @@ def block_apply(p: dict, x, cfg, kind: str, *, mode: str = "train",
         x = x + h
 
     h2 = L.norm_apply(p["ln2"], x, cfg)
-    if is_moe(kind):
+    if is_moe(kind) and cfg.router_score == "sigmoid":
+        h2, stats = MOE.held_apply(p["moe"], h2, cfg, ad=ad.get("moe"),
+                                   masks=masks.get("moe"))
+    elif is_moe(kind):
         h2, aux = MOE.moe_apply(p["moe"], h2, cfg, ctx, ad=ad.get("moe"),
                                 masks=masks.get("moe"))
+        stats = {"balance": aux}
     else:
         h2 = MLP.mlp_apply(p["mlp"], h2, cfg, ad=ad.get("mlp"),
                            masks=masks.get("mlp"))
@@ -145,4 +164,4 @@ def block_apply(p: dict, x, cfg, kind: str, *, mode: str = "train",
     if "post_mlp" in ad:
         h2 = AD.apply_bottleneck(h2, ad["post_mlp"])
     x = x + h2
-    return x, aux, (new_cache or None)
+    return x, stats, (new_cache or None)

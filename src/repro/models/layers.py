@@ -75,13 +75,41 @@ def sinusoidal(pos: jax.Array, d: int) -> jax.Array:
 
 # ------------------------------------------------------------------ rope ----
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def rope_freqs(cfg, dim: int) -> np.ndarray:
+    """(dim // 2,) inverse frequencies: θ^(-2i/dim), or YaRN's blend of them
+    and their 1/factor interpolation (DeepSeek-V3's form: rotations above
+    ``beta_fast`` keep θ's frequency, those below ``beta_slow`` are divided
+    by the factor, a linear ramp between)."""
+    extra = 1.0 / cfg.rope_theta ** (np.arange(0, dim, 2) / dim)
+    if not cfg.yarn_factor:
+        return extra
+
+    def corr_dim(rotations):
+        return (dim * np.log(cfg.yarn_original_max
+                             / (rotations * 2 * np.pi))
+                / (2 * np.log(cfg.rope_theta)))
+
+    lo = max(int(np.floor(corr_dim(cfg.yarn_beta_fast))), 0)
+    hi = min(int(np.ceil(corr_dim(cfg.yarn_beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return extra / cfg.yarn_factor * ramp + extra * (1 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         freqs: np.ndarray | None = None) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  Pairs dim
+    i with i + hd/2; ``freqs`` (hd/2,) overrides θ's."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(
-        -np.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[..., None].astype(jnp.float32) * freqs      # (..., S, half)
+    if freqs is None:
+        freqs = jnp.exp(
+            -np.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        freqs, jnp.float32)                                     # (..., S, half)
     sin, cos = jnp.sin(ang)[..., None, :], jnp.cos(ang)[..., None, :]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:2 * half].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -99,14 +99,14 @@ def _maybe_remat(fn, remat, mode, ctx):
 
 def _run_plan(plan: Plan, params, x, cfg, *, mode, ad, masks, caches, ctx,
               enc_out=None, remat=True, unroll=False):
-    """Execute a plan segment.  Returns (x, aux, new_caches)."""
+    """Execute a plan segment.  Returns (x, stats, new_caches)."""
     ad = ad or {}
     masks = masks or {}
     caches = caches or {}
     shared_p = _get(params, "shared")
     shared_ad = _get(ad, "shared")
     shared_m = _get(masks, "shared")
-    aux = jnp.float32(0.0)
+    aux = {}
 
     def one_block(pj, x, kind, adj, mj, cj):
         real_kind = kind
@@ -125,7 +125,7 @@ def _run_plan(plan: Plan, params, x, cfg, *, mode, ad, masks, caches, ctx,
         def body_fn(x, xs):
             lp, lad, lm, lc = xs
             new_c = {}
-            a_tot = jnp.float32(0.0)
+            a_tot = {}
             for j, kind in enumerate(plan.period):
                 if kind == "shared_attn":
                     pj, adj, mj = shared_p, shared_ad, shared_m
@@ -134,7 +134,7 @@ def _run_plan(plan: Plan, params, x, cfg, *, mode, ad, masks, caches, ctx,
                     adj, mj = _get(lad, f"p{j}"), _get(lm, f"p{j}")
                 cj = _get(lc, f"p{j}") if lc else None
                 x, a, ncj = one_block(pj, x, kind, adj, mj, cj)
-                a_tot = a_tot + a
+                a_tot = BK.merge_stats(a_tot, a)
                 if ncj:
                     new_c[f"p{j}"] = ncj
             return x, (a_tot, new_c or None)
@@ -143,7 +143,7 @@ def _run_plan(plan: Plan, params, x, cfg, *, mode, ad, masks, caches, ctx,
         x, (a_steps, new_body_c) = jax.lax.scan(
             fn, x, (body_p, body_ad, body_m, body_c),
             unroll=plan.repeats if unroll else 1)
-        aux = aux + a_steps.sum()
+        aux = BK.merge_stats(aux, {k: v.sum() for k, v in a_steps.items()})
     else:
         new_body_c = None
 
@@ -162,7 +162,7 @@ def _run_plan(plan: Plan, params, x, cfg, *, mode, ad, masks, caches, ctx,
         blk = functools.partial(one_block, kind=kind, adj=adj, mj=mj, cj=cj)
         wrapped = _maybe_remat(lambda p, y: blk(p, y), remat, mode, ctx)
         x, a, ncj = wrapped(pj, x)
-        aux = aux + a
+        aux = BK.merge_stats(aux, a)
         if ncj:
             new_tail_c[f"t{i}"] = ncj
 
@@ -214,7 +214,7 @@ class Model:
         m["dec"] = _plan_meta(cfg, self.plan,
                               lambda k: BK.block_meta(cfg, k))
         m["final_norm"] = L.norm_meta(cfg)
-        if not cfg.tie_embeddings:
+        if not cfg.tie_embeddings and not cfg.n_classes:   # a classifier
             m["head"] = ParamMeta((cfg.d_model, cfg.vocab_size), cfg.pdtype,
                                   ("embed_fsdp", "vocab"), init="normal")
         return m
@@ -281,6 +281,13 @@ class Model:
             share_skip=False)}
         return out
 
+    @property
+    def count_keys(self) -> tuple[str, ...]:
+        """Keys of the ``counts`` the losses return."""
+        held = self.cfg.router_score == "sigmoid" and any(
+            BK.is_moe(k) for k in self.cfg.layer_pattern)
+        return ("moe_routed", "moe_rows") if held else ()
+
     # ---- materialization ----------------------------------------------------
 
     def init(self, key) -> tuple[dict, dict]:
@@ -297,7 +304,8 @@ class Model:
 
     def forward(self, base, trainable, masks, batch, *, mode="train",
                 cache=None, ctx=None, remat=True):
-        """Returns (logits, aux, new_cache).
+        """Returns (logits, stats, new_cache); ``stats`` sums the blocks'
+        (models/blocks.py ``block_apply``).
 
         batch keys: tokens (B,S) [decoder]; prefix_embeds (B,P,D) [vlm];
         enc_tokens (B,Se) or frames (B,Se,D) [enc-dec]; positions optional.
@@ -306,7 +314,7 @@ class Model:
         ctx = ctx or Ctx()
         adapters = (trainable or {}).get("adapters") or {}
         cache = cache or {}
-        aux = jnp.float32(0.0)
+        aux = {}
 
         enc_out = None
         if cfg.is_encoder_decoder and mode != "decode":
@@ -320,7 +328,7 @@ class Model:
                                  masks=_get(masks, "enc"), caches=None,
                                  ctx=ctx, remat=remat, unroll=self.unroll)
             enc_out = L.norm_apply(base["enc_norm"], ex, cfg)
-            aux = aux + a
+            aux = BK.merge_stats(aux, a)
 
         tokens = batch["tokens"]
         x = L.embed_apply(base["embed"], tokens, cfg)
@@ -338,7 +346,7 @@ class Model:
             ad=_get(adapters, "dec"), masks=_get(masks, "dec"),
             caches=_get(cache, "dec"), ctx=ctx, enc_out=enc_out, remat=remat,
             unroll=self.unroll)
-        aux = aux + a
+        aux = BK.merge_stats(aux, a)
         x = L.norm_apply(base["final_norm"], x, cfg)
         if n_prefix:
             x = x[:, n_prefix:]
@@ -360,6 +368,9 @@ class Model:
         return logits, aux, ({"dec": new_cache} if new_cache else None)
 
     # ---- losses -------------------------------------------------------------
+    # Both return (objective, (loss, metric, counts)): ``counts`` are the
+    # blocks' statistics other than the load-balance loss (the held
+    # experts' ``moe_routed`` and ``moe_rows``; {} for other models).
 
     def lm_loss(self, base, trainable, masks, batch, ctx=None, remat=True):
         logits, aux, _ = self.forward(base, trainable, masks, batch,
@@ -369,7 +380,8 @@ class Model:
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         valid = (targets >= 0).astype(jnp.float32)
         loss = (nll * valid).sum() / jnp.maximum(valid.sum(), 1.0)
-        return loss + self.cfg.router_aux_coef * aux, (loss, aux)
+        bal = aux.pop("balance", jnp.float32(0.0))
+        return loss + self.cfg.router_aux_coef * bal, (loss, bal, aux)
 
     def cls_loss(self, base, trainable, masks, batch, ctx=None, remat=True):
         logits, aux, _ = self.forward(base, trainable, masks, batch,
@@ -378,7 +390,8 @@ class Model:
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         loss = -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
         acc = (logits.argmax(-1) == labels).astype(jnp.float32).mean()
-        return loss + self.cfg.router_aux_coef * aux, (loss, acc)
+        bal = aux.pop("balance", jnp.float32(0.0))
+        return loss + self.cfg.router_aux_coef * bal, (loss, acc, aux)
 
     # ---- serving ------------------------------------------------------------
 

@@ -10,22 +10,25 @@ from repro.core import adapters as AD
 from repro.models import layers as L
 
 
-def mlp_meta(cfg) -> dict:
-    m = {"w1": L.dense_meta(cfg, cfg.d_model, cfg.d_ff,
-                            axes=("embed_fsdp", "mlp"))}
+def mlp_meta(cfg, width: int = 0) -> dict:
+    """``width`` overrides ``cfg.d_ff`` (a dense layer of a MoE model, a
+    shared expert)."""
+    f = width or cfg.d_ff
+    m = {"w1": L.dense_meta(cfg, cfg.d_model, f, axes=("embed_fsdp", "mlp"))}
     if cfg.glu:
-        m["w3"] = L.dense_meta(cfg, cfg.d_model, cfg.d_ff,
+        m["w3"] = L.dense_meta(cfg, cfg.d_model, f,
                                axes=("embed_fsdp", "mlp"))
-    m["w2"] = L.dense_meta(cfg, cfg.d_ff, cfg.d_model,
+    m["w2"] = L.dense_meta(cfg, f, cfg.d_model,
                            axes=("mlp", "embed_fsdp"), out_scale=0.05)
     return m
 
 
-def mlp_adapter_meta(cfg, kind: str) -> dict:
+def mlp_adapter_meta(cfg, kind: str, width: int = 0) -> dict:
+    f = width or cfg.d_ff
     out = {}
-    for name, (di, do) in (("w1", (cfg.d_model, cfg.d_ff)),
-                           ("w3", (cfg.d_model, cfg.d_ff)),
-                           ("w2", (cfg.d_ff, cfg.d_model))):
+    for name, (di, do) in (("w1", (cfg.d_model, f)),
+                           ("w3", (cfg.d_model, f)),
+                           ("w2", (f, cfg.d_model))):
         if name == "w3" and not cfg.glu:
             continue
         if name in cfg.adapter_targets:

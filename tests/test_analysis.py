@@ -31,10 +31,14 @@ def test_parse_collectives():
 
 
 def test_active_params_moe_vs_dense():
+    # Kimi K2 as published: MLA (101.1 M a layer), a dense layer (FFN
+    # 18,432), 60 MoE layers of 384 experts, 8 routed and 1 shared a token
+    # (44.0 M each), a 384-wide router, an untied 163,840-row vocabulary:
+    # 1.0263e12 in all, 3.287e10 active a token
     kimi = get_config("kimi_k2_1t_a32b")
     total, active = AN.active_params(kimi)
-    assert total > 0.9e12                 # ~1T frozen base
-    assert 2.5e10 < active < 4.5e10       # ~32B active
+    assert 1.02e12 < total < 1.04e12
+    assert 3.1e10 < active < 3.4e10
     qwen = get_config("qwen2_0p5b")
     t2, a2 = AN.active_params(qwen)
     assert t2 == a2                       # dense: all params active

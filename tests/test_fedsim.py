@@ -133,7 +133,7 @@ def test_cohort_outputs_are_freed_before_the_next_dispatch(setup,
         def launch(*args):
             held.append(sum(r() is not None for r in refs))
             refs.clear()
-            pc, gc, lc, mc, avg = out = fn(*args)
+            pc, gc, lc, mc, avg, _ = out = fn(*args)
             refs.extend(weakref.ref(x) for x in
                         jax.tree.leaves((pc, gc, lc, mc)))
             return out
@@ -167,6 +167,24 @@ def test_evaluate_lm_returns_mean_nll(setup):
     # bug divided a batch-mean NLL by the label count → a value ≤ ~1
     assert np.isfinite(nll)
     assert nll > 0.9 * np.log(cfg.vocab_size)
+
+
+def test_evaluate_traces_its_step_once(setup):
+    """Every evaluation of a run reuses one jitted eval step: an eval round
+    after the first traces and compiles nothing (a benchmark window holds
+    eval rounds)."""
+    from repro.federated import client as CL
+    from repro.federated.server import evaluate
+    cfg, train, test, parts = setup
+    model = Model(cfg, peft="bea", unroll=True)
+    base, trainable = model.init(jax.random.key(0))
+    fc = FedConfig(batch_size=8, eval_batches=2)
+    accs = [evaluate(model, base, trainable, None, test, fc)
+            for _ in range(3)]
+    ev = CL.make_eval_step(model, fc.task)
+    assert ev is CL.make_eval_step(model, fc.task)
+    assert ev._cache_size() == 1
+    assert accs[0] == accs[1] == accs[2]
 
 
 def test_batch_rng_stream_incorporates_seed():
