@@ -80,28 +80,33 @@ def bytes_up(adapters: Any, masks: Any | None, dtype_bytes: int = 4,
 
 
 def prune_tree(adapters: Any, masks: Any | None):
-    """Zero all masked-out ranks (transmission-equivalent state)."""
+    """Zero all masked-out ranks (transmission-equivalent state).  Branches
+    on shapes alone, so it traces with the masks as arguments
+    (``federated.server.round_programs``)."""
     if masks is None:
         return adapters
 
     def prune_module(mod, msk):
-        m = jnp.asarray(msk)
+        # a select, not a multiply by 0/1: compiled and eager agree to the
+        # bit (XLA rewrites a multiply by a converted mask into a select,
+        # which writes +0 where the multiply kept a negative's sign)
+        m = jnp.asarray(msk, bool)
         out = dict(mod)
         # broadcast mask over expert axis if the adapter is per-expert
         am = m
         if mod["A"].ndim == m.ndim + 2:            # (E, r, d) vs (r,)
             am = m[..., None, :] if m.ndim else m
-        out["A"] = mod["A"] * am[..., :, None].astype(mod["A"].dtype) \
+        out["A"] = jnp.where(am[..., :, None], mod["A"], 0) \
             if mod["A"].ndim >= 2 else mod["A"]
         bm = m
         if mod["B"].ndim == m.ndim + 2:
             bm = m[..., None, :] if m.ndim else m
-        out["B"] = mod["B"] * bm[..., None, :].astype(mod["B"].dtype)
+        out["B"] = jnp.where(bm[..., None, :], mod["B"], 0)
         if "E" in mod:
             em = m
             if mod["E"].ndim == m.ndim + 1:        # (E, r) vs (r,)
                 em = m[..., None, :] if m.ndim else m
-            out["E"] = mod["E"] * em.astype(mod["E"].dtype)
+            out["E"] = jnp.where(em, mod["E"], 0)
         return out
 
     def walk(ad, msk):

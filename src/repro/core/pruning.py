@@ -26,24 +26,20 @@ def _is_module(x) -> bool:
     return isinstance(x, dict) and "A" in x and "B" in x
 
 
-def module_alive(mask) -> np.ndarray:
-    """Per-stacked-layer liveness: (lead...,) bool (any rank surviving)."""
-    m = np.asarray(mask, bool)
-    return m.any(axis=-1)
-
-
 def trainable_gate(adapters: Any, masks: Any) -> Any:
     """Pytree of float gates aligned with ``adapters`` leaves.
 
     For a module whose mask is all-False (per stacked layer), every leaf of
     that module gets gate 0 for that layer — the optimizer stops updating it.
+    Traceable in the masks: the round loop builds it inside a compiled
+    program (``federated.server.round_programs``).
     """
     def walk(ad, msk):
         if _is_module(ad):
             if msk is None:
                 return jax.tree.map(lambda x: jnp.ones((), x.dtype), ad)
-            alive = jnp.asarray(np.asarray(msk, bool).any(-1),
-                                jnp.float32)                    # (lead...,)
+            alive = jnp.any(jnp.asarray(msk, bool), -1).astype(
+                jnp.float32)                                    # (lead...,)
             out = {}
             for k, v in ad.items():
                 extra = v.ndim - alive.ndim

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,7 @@ from repro.federated import client as CL
 from repro.federated import devices as DV
 from repro.fedsim import pipeline as PL
 from repro.fedsim import transport as T
-from repro.fedsim.cohort import client_batch_rng
+from repro.fedsim.cohort import client_batch_rng, stack_params
 from repro.secagg import dp as DP
 from repro.secagg import protocol as SA
 
@@ -176,15 +176,14 @@ def _init_run(model, strategy, fc: FedConfig):
 
 
 def pin_params(tree, masks=None, sharding=None):
-    """Re-commit loop-carried state to one canonical placement.
+    """Commit state to one canonical placement, once, before a run's loop.
 
-    Round 0's params are uncommitted host/eager arrays; from round 1 on they
-    are committed jit outputs.  The placement flip re-lowers (and re-compiles)
-    the *identical* jaxpr once — a silent multi-second duplicate XLA compile
-    of the client-step / cohort body.  Pinning the broadcast state every round
-    makes all dispatches lower against the same sharding, so compile counts
-    are flat after the first round (asserted in tests/test_obs.py via
-    obs.profile.compile_stats).
+    Fresh params are uncommitted arrays; jit outputs are committed.  A
+    program called with both lowers (and compiles) the *identical* jaxpr
+    twice — a silent duplicate XLA compile in round 1.  Pinning the initial
+    state, and ``round_programs`` placing every round's outputs on the same
+    sharding, keep compile counts flat after the first round (asserted in
+    tests/test_obs.py via obs.profile.compile_stats).
     """
     dst = sharding if sharding is not None else jax.devices()[0]
     tree = jax.device_put(tree, dst)
@@ -193,20 +192,55 @@ def pin_params(tree, masks=None, sharding=None):
     return tree, masks
 
 
-def _arbitrate(strategy, trainable, local_masks, masks, masks_np, rnd):
-    """FedArb + RankDet after aggregation → (trainable, masks, masks_np).
-    Traced as an ``arbitrate`` span counting ``pruned``: ranks switched off
-    this round."""
+class RoundPrograms(NamedTuple):
+    """The server's per-round work on the adapter tree (``round_programs``)."""
+    prune: Callable     # (trainable, masks_np) → (trainable, masks)
+    place: Callable     # (bc, masks) → (bc, masks, gate, stacked)
+
+
+def round_programs(strategy, sharding, slots: int = 0,
+                   slot_sharding=None) -> RoundPrograms:
+    """One run's round-loop work on the adapter tree as two compiled
+    programs, each one dispatch where eager code took one per leaf.
+
+    ``prune`` zeroes the trainable adapters' masked-out ranks (CommPru,
+    ``COMM.prune_tree``) and returns the masks on the device: before the
+    broadcast and after arbitration.  ``place`` puts the broadcast state
+    and its masks on ``sharding``, builds the strategy's optimizer gate
+    and, for ``slots`` > 0, the cohort program's per-client copies
+    (``cohort.stack_params``) split over ``slot_sharding``.
+
+    Mask values are arguments, so a round whose masks changed compiles
+    nothing.  ``prune`` takes the host masks and ``place`` device masks
+    (``prune``'s, or pinned ones), each always in one placement, and
+    every output lands on the same sharding every round.
+    """
+    def prune(trainable, masks_np):
+        return dict(trainable, adapters=COMM.prune_tree(
+            trainable["adapters"], masks_np)), masks_np
+
+    def place(bc, masks):
+        stacked = stack_params(bc, slots) if slots else None
+        return bc, masks, strategy.optimizer_gate(bc, masks), stacked
+
+    return RoundPrograms(
+        jax.jit(prune, out_shardings=sharding),
+        jax.jit(place, out_shardings=(sharding, sharding, sharding,
+                                      slot_sharding)))
+
+
+def _arbitrate(strategy, trainable, local_masks, masks, masks_np, rnd,
+               prune):
+    """FedArb + RankDet after aggregation → (trainable, masks, masks_np),
+    pruned by ``prune`` (``round_programs``).  Traced as an ``arbitrate``
+    span counting ``pruned``: ranks switched off this round."""
     if strategy.uses_masks():
         tr = OBS.get_tracer()
         asp = tr.begin("arbitrate", kind="stage", rnd=int(rnd))
         live0 = MK.count_true(masks_np) if tr.enabled else 0
         strategy.last_aggregate = trainable   # FedARA-global ablation hook
         masks_np = strategy.arbitrate(rnd, local_masks, masks_np)
-        masks = jax.tree.map(jnp.asarray, masks_np)
-        trainable = dict(trainable,
-                         adapters=COMM.prune_tree(trainable["adapters"],
-                                                  masks_np))
+        trainable, masks = prune(trainable, masks_np)
         if tr.enabled:
             asp.count(pruned=live0 - MK.count_true(masks_np))
         asp.end()
@@ -214,17 +248,14 @@ def _arbitrate(strategy, trainable, local_masks, masks, masks_np, rnd):
 
 
 def _arbitrate_votes(strategy, trainable, vote_sums, n_reporting, masks,
-                     masks_np, rnd):
+                     masks_np, rnd, prune):
     """Aggregate-only FedArb: the secagg server sees vote *sums*, never a
     client's mask (core.arbitration.arbitrate_from_votes)."""
     if strategy.uses_masks():
         strategy.last_aggregate = trainable
         masks_np = strategy.arbitrate_votes(rnd, vote_sums, n_reporting,
                                             masks_np)
-        masks = jax.tree.map(jnp.asarray, masks_np)
-        trainable = dict(trainable,
-                         adapters=COMM.prune_tree(trainable["adapters"],
-                                                  masks_np))
+        trainable, masks = prune(trainable, masks_np)
     return trainable, masks, masks_np
 
 
@@ -261,15 +292,16 @@ def validate_privacy_config(fc: FedConfig) -> None:
 
 
 def _private_round(strategy, bc, encoded, sel, masks, masks_np, fc, rnd,
-                   history, accountant, pipe):
+                   history, accountant, pipe, prune=None):
     """Shared secagg/DP aggregation step (seq oracle, cohort runner, and
     SLoRA stage 1): routes the pipeline's encoded delta wires through
-    ``secagg.protocol.aggregate_round``, arbitrates from vote sums, and
-    records protocol accounting + the ε trajectory in the history."""
+    ``secagg.protocol.aggregate_round``, arbitrates from vote sums (pruned
+    by ``prune``, which a strategy with masks needs), and records protocol
+    accounting + the ε trajectory in the history."""
     agg = pipe.aggregate_private(bc, encoded, sel, masks_np, rnd)
     trainable, masks, masks_np = _arbitrate_votes(
         strategy, agg.trainable, agg.vote_sums, agg.n_reporting, masks,
-        masks_np, rnd)
+        masks_np, rnd, prune)
     if agg.secagg is not None:
         history.record_secagg({
             "rnd": rnd,
@@ -390,6 +422,8 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
     base, trainable, masks, masks_np, n_rank_units, opt, rng = \
         _init_run(model, strategy, fc)
     step_fn = CL.make_train_step(model, opt, fc.task)
+    dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    rp = round_programs(strategy, dev)
     pipe = PL.UploadPipeline(fc, strategy)
     private = SA.wants_private(fc)
     accountant = make_accountant(fc, len(parts))
@@ -406,6 +440,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
         base, trainable = _run_stage1(model, strategy, base, trainable,
                                       parts, train, fc, opt, rng, logs,
                                       history, accountant)
+    trainable, masks = pin_params(trainable, masks, sharding=dev)
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
@@ -413,13 +448,10 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
                                               len(parts)), replace=False)
         # ---- CommPru'd broadcast (delta-coded when a codec is on) --------
         if masks_np is not None:
-            trainable = dict(trainable,
-                             adapters=COMM.prune_tree(trainable["adapters"],
-                                                      masks_np))
+            trainable, masks = rp.prune(trainable, masks_np)
         bc, down_per = pipe.broadcast(trainable, masks_np)
-        bc, masks = pin_params(bc, masks)
+        bc, masks, gate, _ = rp.place(bc, masks)
         down = down_per * len(sel)
-        gate = strategy.optimizer_gate(bc, masks_np)
 
         results, local_masks, encoded = [], [], []
         for cid in sel:
@@ -453,7 +485,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
             # ---- secagg / DP: the server only sees the field aggregate ---
             trainable, masks, masks_np, agg = _private_round(
                 strategy, bc, encoded, sel, masks, masks_np, fc, rnd,
-                history, accountant, pipe)
+                history, accountant, pipe, rp.prune)
             up = agg.up_bytes + sum(e.nbytes for e in encoded)
             down += agg.down_bytes
             protocol_s = agg.time_s
@@ -463,7 +495,8 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
             up = sum(e.nbytes for e in encoded)
             # ---- FedArb + RankDet ---------------------------------------
             trainable, masks, masks_np = _arbitrate(
-                strategy, trainable, local_masks, masks, masks_np, rnd)
+                strategy, trainable, local_masks, masks, masks_np, rnd,
+                rp.prune)
             protocol_s = 0.0
 
         # rank trajectory → trace (FedARA's per-round allocation decision)

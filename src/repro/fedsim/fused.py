@@ -164,11 +164,11 @@ def run_fused(model, strategy, parts, train, test, fc,
     logs: list[SV.RoundLog] = history["rounds"]
     t0 = time.perf_counter()
 
-    gate = strategy.optimizer_gate(trainable, masks_np)
     # shape-only byte accounting (identity codec): constant across rounds
     up_per = strategy.comm_up(trainable, masks_np)
     base, _ = SV.pin_params(base, sharding=rep)
-    trainable, masks = SV.pin_params(trainable, masks, sharding=rep)
+    trainable, masks, gate, _ = SV.round_programs(strategy, rep).place(
+        trainable, masks)
 
     rnd = 0
     while rnd < fc.rounds:

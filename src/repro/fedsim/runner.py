@@ -29,11 +29,11 @@ from typing import Callable
 
 import jax
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import obs as OBS
 from repro.core import masks as MK
 from repro.core import pruning as PR
-from repro.core import comm as COMM
 from repro.data.synthetic import Dataset, batches
 from repro.federated import client as CL
 from repro.federated import devices as DV
@@ -91,13 +91,16 @@ def run_cohort(model, strategy, parts, train, test, fc,
     step_fn = CL.make_train_step(model, opt, fc.task)     # ragged fallback
     mesh = CH.cohort_mesh()
     cohort_fn = CH.make_cohort_fn(model, opt, fc.task, mesh=mesh)
-    # broadcast state is pinned replicated-on-mesh so every dispatch lowers
-    # against the same sharding (see SV.pin_params)
-    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     ndev = len(jax.devices())
     cpr = min(fc.clients_per_round, len(parts))
     c_pad = -(-cpr // ndev) * ndev                        # shardable cohort
     lanes = CH.lane_count(parts, fc, c_pad, ndev)
+    # broadcast state replicated on the mesh, its per-client copies split
+    # over it, the same every round (see SV.round_programs)
+    rep = jax.sharding.NamedSharding(mesh, P())
+    rp = SV.round_programs(strategy, rep, slots=c_pad,
+                           slot_sharding=jax.sharding.NamedSharding(
+                               mesh, P("clients")))
 
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
@@ -115,6 +118,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
         base, trainable = SV._run_stage1(model, strategy, base, trainable,
                                          parts, train, fc, opt, rng, logs,
                                          history, accountant)
+    trainable, masks = SV.pin_params(trainable, masks, sharding=rep)
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
@@ -125,13 +129,11 @@ def run_cohort(model, strategy, parts, train, test, fc,
         sel = rng.choice(len(parts), size=cpr, replace=False)
         # ---- CommPru'd broadcast (delta-coded when a codec is on) --------
         if masks_np is not None:
-            trainable = dict(trainable,
-                             adapters=COMM.prune_tree(trainable["adapters"],
-                                                      masks_np))
+            trainable, masks = rp.prune(trainable, masks_np)
         bc, down_per = pipe.broadcast(trainable, masks_np)
-        bc, masks = SV.pin_params(bc, masks, sharding=rep)
+        # the cohort's C = c_pad slots each start from a copy of bc
+        bc, masks, gate, stacked = rp.place(bc, masks)
         down = down_per * len(sel)
-        gate = strategy.optimizer_gate(bc, masks_np)
 
         # ---- dropout / straggler draws (fixed order → determinism) ------
         drops = ev_rng.random(len(sel)) < fc.dropout
@@ -145,7 +147,6 @@ def run_cohort(model, strategy, parts, train, test, fc,
         pc = gc = lc = mc = avg = None
         cohort_idx = {}
         if cohort is not None:
-            stacked = CH.stack_params(bc, len(cohort.weights))
             plan = CH.schedule(cohort.n_steps, cohort.step_mask.shape[1],
                                lanes, ndev)
         psp.end()
@@ -257,7 +258,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
             # still pays — and records — the advertise/share phases)
             trainable, masks, masks_np, agg = SV._private_round(
                 strategy, bc, encoded, sel, masks, masks_np, fc, rnd,
-                history, accountant, pipe)
+                history, accountant, pipe, rp.prune)
             up = agg.up_bytes + sum(e.nbytes for e in encoded)
             down += agg.down_bytes
             protocol_s = agg.time_s
@@ -270,7 +271,8 @@ def run_cohort(model, strategy, parts, train, test, fc,
             else:
                 trainable = pipe.aggregate(bc, encoded, rnd=rnd)
             trainable, masks, masks_np = SV._arbitrate(
-                strategy, trainable, local_masks, masks, masks_np, rnd)
+                strategy, trainable, local_masks, masks, masks_np, rnd,
+                rp.prune)
 
         # rank trajectory → trace (FedARA's per-round allocation decision)
         if tr.has_readers and masks_np:
@@ -332,6 +334,8 @@ def run_async(model, strategy, parts, train, test, fc,
     base, trainable, masks, masks_np, n_rank_units, opt, rng = \
         SV._init_run(model, strategy, fc)
     step_fn = CL.make_train_step(model, opt, fc.task)
+    dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    rp = SV.round_programs(strategy, dev)
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
 
@@ -345,6 +349,7 @@ def run_async(model, strategy, parts, train, test, fc,
         base, trainable = SV._run_stage1(model, strategy, base, trainable,
                                          parts, train, fc, opt, rng, logs,
                                          history)
+    trainable, masks = SV.pin_params(trainable, masks, sharding=dev)
 
     buffer_k = fc.buffer_k or min(fc.clients_per_round, len(parts))
     concurrency = fc.async_concurrency or 2 * buffer_k
@@ -363,14 +368,13 @@ def run_async(model, strategy, parts, train, test, fc,
         # per-client DeltaChannel: a stale client's broadcast stream is
         # delta-coded against *its own* last reconstruction
         bc, down = pipe.broadcast(trainable, masks_np, endpoint=cid)
-        bc, bc_masks = SV.pin_params(bc, masks)
+        bc, bc_masks, gate, _ = rp.place(bc, masks)
         pend_down += down
         n_b = _n_local_batches(len(parts[cid]), fc)
         link = T.link_for(device_of(cid))
         # upload size is only known post-encode; model it as symmetric
         finish = (now + link.transfer_s(down) + _compute_s(cid, fc, n_b, slow)
                   + link.transfer_s(down))
-        gate = strategy.optimizer_gate(bc, masks_np)
         if not dropped:
             stash[seq_no] = (bc, bc_masks, masks_np, gate, version)
         heapq.heappush(heap, (finish, seq_no, cid, dropped))
@@ -424,7 +428,8 @@ def run_async(model, strategy, parts, train, test, fc,
                         agg, pk["adapters"], (gk or {}).get("adapters"),
                         n_rank_units))
             trainable, masks, masks_np = SV._arbitrate(
-                strategy, trainable, local_masks, masks, masks_np, agg)
+                strategy, trainable, local_masks, masks, masks_np, agg,
+                rp.prune)
             if OBS.get_tracer().has_readers and masks_np:
                 history.record_ranks(agg, masks_np,
                                      votes=MK.vote_fractions(local_masks))

@@ -400,6 +400,8 @@ def _traced_run(setup, path, **fc_kw):
     cfg, train, test, parts = setup
     rounds = fc_kw.pop("rounds", 2)
     strat = all_strategies(rounds=rounds)[fc_kw.pop("strategy", "fedlora")]
+    for k, v in fc_kw.pop("strategy_params", {}).items():
+        setattr(strat, k, v)
     model = Model(cfg, peft=strat.peft, unroll=True)
     fc = FedConfig(rounds=rounds, clients_per_round=3, batch_size=16,
                    max_local_batches=2, eval_every=rounds, lr=3e-3, **fc_kw)
@@ -519,14 +521,30 @@ def fedara_trace(setup, tmp_path_factory):
     return h, E.read_jsonl(path)
 
 
-def test_compile_flat_after_first_round(fedara_trace):
-    """ISSUE acceptance: compile-span accounting on a traced 3-round cohort
-    run shows zero new compilations after round 1.  Rounds are 0-indexed in
+@pytest.fixture(scope="module")
+def fedara_masks_trace(setup, tmp_path_factory):
+    """A traced 4-round fedara cohort run with no warm-up, so the rank
+    budget falls from round 0 and the masks change value every round."""
+    path = str(tmp_path_factory.mktemp("fedara_masks") / "fedara.jsonl")
+    h = _traced_run(setup, path, runner="cohort", strategy="fedara",
+                    rounds=4, strategy_params={"warmup_rounds": 0})
+    return h, E.read_jsonl(path)
+
+
+@pytest.mark.parametrize("trace", ["fedara_trace", "fedara_masks_trace"])
+def test_compile_flat_after_first_round(trace, request):
+    """Compile-span accounting on a traced cohort run
+    shows zero new compilations after round 1.  Rounds are 0-indexed in
     the trace, so 'after round 1' == no backend compile under a round span
     with rnd >= 1 (the eval span buckets separately — evaluating at the end
-    legitimately compiles the eval step once)."""
+    legitimately compiles the eval step once).  The round loop's prune and
+    placement take the masks as arguments: masks that change value between
+    rounds compile nothing either."""
     from repro.obs import profile as P
-    h, events = fedara_trace
+    h, events = request.getfixturevalue(trace)
+    if trace == "fedara_masks_trace":
+        live = [log.live_ranks for log in h["rounds"]]
+        assert live[0] > live[1] > live[2], live
     cs = P.compile_stats(events)
     assert cs["after_first_round"] == 0, cs["by_round"]
     assert all(rnd == 0 for rnd in cs["by_round"]), cs["by_round"]
