@@ -1,8 +1,7 @@
-"""fedsim benchmark: cohort-vs-sequential round throughput, fused K-round
-blocks (one XLA dispatch per K rounds — fedsim/fused.py) vs the same
-oracle, pow-2 re-bucketing padding waste, delta-codec byte ratios +
-convergence-vs-bytes curves (identity / int8 / topk / signsgd / powersgd
-through the shared upload pipeline), and async event throughput.
+"""fedsim benchmark: cohort-vs-sequential round throughput, delta-codec
+byte ratios + convergence-vs-bytes curves (identity / int8 / topk /
+signsgd / powersgd through the shared upload pipeline), and async event
+throughput.
 
 The throughput comparison runs in this process on the devices JAX finds
 (the shard_map cohort axis spans all of them; on a CPU host, fake several
@@ -28,16 +27,14 @@ import os
 import time
 
 import jax
-import numpy as np
 
 from benchmarks import common as C
 from benchmarks.common import steady_state
 from repro.configs.distilbert import MINI
 from repro.data.synthetic import make_classification
 from repro.federated.baselines import all_strategies
-from repro.federated.partition import dirichlet_partition, iid_partition
+from repro.federated.partition import iid_partition
 from repro.federated.server import FedConfig, run_federated
-from repro.fedsim.cohort import build_cohort
 from repro.models import Model
 
 JSON_PATH = os.environ.get("BENCH_FEDSIM_JSON", "BENCH_fedsim.json")
@@ -85,69 +82,6 @@ def _measure(quick: bool) -> dict:
                           else rec["seq_round_s"] / rec["cohort_round_s"])
         out["rows"].append(rec)
 
-    # fused multi-round blocks (fedsim/fused.py) vs the seq oracle, in the
-    # regime fusion targets: cross-device-style tiny local work (1-layer
-    # encoder, one local batch of 8), where per-round dispatch + host
-    # orchestration dominate.  CPU-faked "devices" share cores, so parallel
-    # compute cannot win here; what fusion eliminates — K-1 of every K
-    # dispatches, host cohort pulls, and python round scaffolding — is the
-    # whole measurable advantage, so seq is re-timed at this exact config.
-    # For K > 1 on_round fires in a replay burst per block, so marks land
-    # at block boundaries and s/round = block_s / K.  The final interval is
-    # excluded everywhere (marks[:-1]): it absorbs the end-of-run eval
-    # (compile + run), which otherwise dominates a K-round block.
-    cfg_f = MINI.with_(n_layers=1, layer_pattern=("attn",))
-    train_f = make_classification(1600, 20, cfg_f.vocab_size, 16, seed=1)
-    test_f = make_classification(200, 20, cfg_f.vocab_size, 16, seed=2)
-    parts_f = iid_partition(train_f.labels, 20, seed=0)
-
-    def timed_fused(K, cpr, n_blocks):
-        KK = max(K, 1)
-        rounds = KK * n_blocks
-        strat = all_strategies(rounds=rounds)["fedlora"]
-        model = Model(cfg_f, peft=strat.peft, unroll=True)
-        fc = FedConfig(rounds=rounds, clients_per_round=cpr, batch_size=8,
-                       max_local_batches=1, eval_every=10**6, lr=3e-3,
-                       runner="seq" if K == 0 else "cohort", fuse_rounds=KK)
-        marks = [time.perf_counter()]
-        run_federated(model, strat, parts_f, train_f, test_f, fc,
-                      on_round=lambda r, log: (
-                          marks.append(time.perf_counter())
-                          if (r + 1) % KK == 0 else None))
-        block_s, n = steady_state(marks[:-1], warmup=1)
-        return block_s / KK, n
-
-    for cpr in ([4] if quick else [2, 4, 8]):
-        seq_s, _ = timed_fused(0, cpr, 6 if quick else 10)
-        for K in ([1, 4] if quick else [1, 4, 16]):
-            rs, n = timed_fused(K, cpr, 4 if quick else 5)
-            noisy = n == 0 or not rs > 0 or not seq_s > 0
-            out["rows"].append(
-                {"cpr": "{0}_K{1}".format(cpr, K), "fused_K": K,
-                 "fused_round_s": rs, "fused_samples": n,
-                 "seq_round_s": seq_s, "noisy": noisy,
-                 "speedup": float("nan") if noisy else seq_s / rs})
-
-    # re-bucketing: mean padding waste (dead steps / rectangle area) on a
-    # dirichlet-skewed split, with and without the pow-2 step-axis snap.
-    # Host-side cohort construction only — no training.
-    sk = dirichlet_partition(train.labels, 40, alpha=0.3, seed=0)
-    fcb = FedConfig(rounds=1, clients_per_round=8, batch_size=16,
-                    max_local_batches=16)
-    rsel = np.random.default_rng(0)
-    wf, wb = [], []
-    for r in range(20):
-        sel = [int(c) for c in rsel.choice(40, size=8, replace=False)]
-        full = build_cohort(train, sk, sel, fcb, r, 8)
-        snug = build_cohort(train, sk, sel, fcb, r, 8, bucket=True)
-        if full is None:
-            continue
-        real = float(full.step_mask.sum())
-        wf.append(1.0 - real / full.step_mask.size)
-        wb.append(1.0 - real / snug.step_mask.size)
-    out["rebucket"] = {"padding_waste_full": sum(wf) / len(wf),
-                       "padding_waste_pow2": sum(wb) / len(wb)}
-
     # transport: bytes per round + convergence-vs-bytes under each codec
     # (cohort runner, same seeds → same client draws across codecs)
     out["codec"], out["convergence"] = {}, {}
@@ -183,23 +117,11 @@ def main(quick: bool = False) -> None:
 
     rows = []
     for rec in out["rows"]:
-        if "fused_round_s" in rec:
-            rows.append(C.row(f"fedsim/fused_speedup_cpr{rec['cpr']}",
-                              f"{rec['speedup']:.3f}",
-                              seq_s=f"{rec['seq_round_s']:.4f}",
-                              fused_s=f"{rec['fused_round_s']:.4f}",
-                              K=rec["fused_K"], ndev=out["ndev"],
-                              noisy=int(rec["noisy"])))
-        else:
-            rows.append(C.row(f"fedsim/cohort_speedup_cpr{rec['cpr']}",
-                              f"{rec['speedup']:.3f}",
-                              seq_s=f"{rec['seq_round_s']:.3f}",
-                              cohort_s=f"{rec['cohort_round_s']:.3f}",
-                              ndev=out["ndev"], noisy=int(rec["noisy"])))
-    rb = out["rebucket"]
-    rows.append(C.row("fedsim/rebucket_padding_waste",
-                      f"{rb['padding_waste_pow2']:.3f}",
-                      full=f"{rb['padding_waste_full']:.3f}"))
+        rows.append(C.row(f"fedsim/cohort_speedup_cpr{rec['cpr']}",
+                          f"{rec['speedup']:.3f}",
+                          seq_s=f"{rec['seq_round_s']:.3f}",
+                          cohort_s=f"{rec['cohort_round_s']:.3f}",
+                          ndev=out["ndev"], noisy=int(rec["noisy"])))
     ident = out["codec"]["identity"]
     for name, b in out["codec"].items():
         final_loss = out["convergence"][name][-1][1]

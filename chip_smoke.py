@@ -13,15 +13,13 @@ One chip, one process (a chip belongs to one process at a time):
                  compiled by Mosaic and compared with kernels/ref.py.
   fedara_cohort  FedARA rounds through ``run_federated(runner="cohort")`` at
                  DistilBERT width on a Dirichlet α=0.1 split.
-  fused          FedLoRA with ``fuse_rounds=2`` (donated carry, one program
-                 per two rounds) against the eager cohort loop, same seed.
   serving        ``build_engine`` + ``serve_requests`` at Qwen2-0.5B width:
                  requests over tenants at mixed ranks, batched outputs equal
                  to unbatched ones.
 
 ``--four-chips`` runs only the cross-chip path: FedLoRA cohort rounds with
 8 clients per round whose client axis is ``shard_map``-ped over 4 chips
-against the sequential reference, plus one fused block.
+against the sequential reference.
 
 Every phase prints one ``phase`` line (compile seconds, run seconds, device
 memory).  These are smoke timings, not benchmark numbers.  The last line is
@@ -54,7 +52,6 @@ from repro.federated.baselines import all_strategies  # noqa: E402
 from repro.federated.partition import (dirichlet_partition,  # noqa: E402
                                        iid_partition)
 from repro.federated.server import FedConfig, run_federated  # noqa: E402
-from repro.fedsim import fused as FU  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.kernels.bea_batched import bea_batched  # noqa: E402
 from repro.kernels.bea_fused import bea_dense  # noqa: E402
@@ -314,27 +311,6 @@ def phase_fedara(cfg, seed: int, rounds=3, cpr=4, n_train=1600, n_test=256):
             "loop_wall_s": f"{h['wall_s']:.3f}"}
 
 
-def phase_fused(cfg, seed: int, rounds=4, cpr=4, k=2, n_train=1600,
-                n_test=256):
-    train, test = _data(cfg, seed, n_train, n_test)
-    parts = iid_partition(train.labels, N_CLIENTS, seed)
-    kw = dict(rounds=rounds, clients_per_round=cpr, seed=seed,
-              runner="cohort", max_local_batches=4)
-    h_eager, strat, _ = _fed_run(cfg, "fedlora", parts, train, test, **kw)
-    ok, why = FU.eligible(FedConfig(**kw, fuse_rounds=k), strat, parts)
-    if not ok:
-        raise AssertionError(f"fused path not eligible: {why}")
-    h_fused, _, _ = _fed_run(cfg, "fedlora", parts, train, test, **kw,
-                             fuse_rounds=k)
-    _round_lines("eager", h_eager)
-    _round_lines(f"fused_K{k}", h_fused)
-    worst = _compare("fused vs eager", h_eager, h_fused)
-    return {"rounds": rounds, "fuse_rounds": k,
-            "max_rel_loss_diff": f"{worst:.3e}", "loss_rtol": LOSS_RTOL,
-            "eager_loop_wall_s": f"{h_eager['wall_s']:.3f}",
-            "fused_loop_wall_s": f"{h_fused['wall_s']:.3f}"}
-
-
 # ---------------------------------------------------------------------------
 # serving
 # ---------------------------------------------------------------------------
@@ -430,17 +406,12 @@ def phase_four_chips(cfg, seed: int, rounds=2, cpr=8, n_clients=16,
                            **kw)
     h_coh, _, _ = _fed_run(cfg, "fedlora", parts, train, test,
                            runner="cohort", **kw)
-    h_fus, _, _ = _fed_run(cfg, "fedlora", parts, train, test,
-                           runner="cohort", fuse_rounds=rounds, **kw)
     _round_lines("seq", h_seq)
     _round_lines("cohort_4chip", h_coh)
-    _round_lines(f"fused_K{rounds}_4chip", h_fus)
     w_coh = _compare("cohort vs seq", h_seq, h_coh)
-    w_fus = _compare("fused vs cohort", h_coh, h_fus)
     return {"devices": ndev, "rounds": rounds, "clients_per_round": cpr,
             "client_shards": split,
             "cohort_vs_seq_max_rel_loss_diff": f"{w_coh:.3e}",
-            "fused_vs_cohort_max_rel_loss_diff": f"{w_fus:.3e}",
             "loss_rtol": LOSS_RTOL}
 
 
@@ -469,8 +440,6 @@ def main(argv=None) -> int:
         run_phase("kernels", lambda: phase_kernels(args.seed), clock, failed)
         run_phase("fedara_cohort",
                   lambda: phase_fedara(distilbert.CONFIG, args.seed),
-                  clock, failed)
-        run_phase("fused", lambda: phase_fused(distilbert.CONFIG, args.seed),
                   clock, failed)
         run_phase("serving",
                   lambda: phase_serving(qwen2_0p5b.CONFIG, args.seed),

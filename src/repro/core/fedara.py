@@ -40,6 +40,10 @@ class Strategy:
     def uses_masks(self) -> bool:
         return False
 
+    def stage1_rounds(self, total_rounds: int) -> int:
+        """Rounds of a stage run before the adapter rounds (SLoRA's)."""
+        return 0
+
     def budget(self, rnd: int) -> int | None:
         return None
 

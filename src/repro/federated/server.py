@@ -68,13 +68,8 @@ class FedConfig:
     eval_batches: int = 16
     # ---- fedsim (device-parallel simulation / transport / async) ----------
     runner: str = "seq"                 # seq | cohort | async
-    fuse_rounds: int = 1                # cohort: scan K rounds per dispatch
-                                        # (1 ≡ eager; >1 needs the fast path,
-                                        # else falls back — fedsim/fused.py)
     opt_state_dtype: str = "float32"    # adam moment storage:
                                         # float32 | bfloat16 | int8
-    rebucket: bool = False              # cohort: per-round pow-2 step-axis
-                                        # re-bucketing (skewed partitions)
     codec: str = "identity"      # identity | int8 | topk | signsgd | powersgd
     powersgd_rank: int = 2              # q for the powersgd codec
     dropout: float = 0.0                # P(selected client never reports)
@@ -328,7 +323,7 @@ def make_accountant(fc: FedConfig, n_clients: int):
 
 
 def _run_stage1(model, strategy, base, trainable, parts, train, fc, opt, rng,
-                logs, history, accountant=None):
+                history, accountant=None):
     """SLoRA stage 1: sparse full-FT rounds before LoRA (baselines.SLoRA).
     Consumes ``rng`` selections exactly like main rounds, so runners that
     share the selection stream stay aligned with the oracle.
@@ -410,6 +405,114 @@ def _run_stage1(model, strategy, base, trainable, parts, train, fc, opt, rng,
     return base0, trainable
 
 
+# ---------------------------------------------------------------------------
+# Round and run bookkeeping shared by the round loops (seq, cohort, async)
+# ---------------------------------------------------------------------------
+
+class _Run(NamedTuple):
+    """What a run's shared bookkeeping reads, fixed for the run."""
+    model: Any
+    test: Dataset
+    fc: FedConfig
+    history: OBS.RunRecorder
+    t0: float                   # wall clock at the start of the run
+    n_rank_units: int
+    accountant: Any             # secagg.dp.RDPAccountant, or None
+    on_round: Callable | None
+
+
+def _start_run(model, strategy, parts, train, test, fc, on_round, runner,
+               sharding, extra_keys, accountant=None):
+    """Run start: initial state, the recorder (whose ``run`` span opens
+    here) and the wall clock, SLoRA stage 1, and the adapter state pinned
+    on ``sharding``.  Returns ``(run, base, trainable, masks, masks_np,
+    opt, rng, s1_rounds)``."""
+    base, trainable, masks, masks_np, n_rank_units, opt, rng = \
+        _init_run(model, strategy, fc)
+    history = OBS.RunRecorder(runner, fc, extra_keys=extra_keys)
+    t0 = time.perf_counter()
+    s1_rounds = strategy.stage1_rounds(fc.rounds)
+    if s1_rounds:
+        base, trainable = _run_stage1(model, strategy, base, trainable,
+                                      parts, train, fc, opt, rng, history,
+                                      accountant)
+    trainable, masks = pin_params(trainable, masks, sharding=sharding)
+    run = _Run(model, test, fc, history, t0, n_rank_units, accountant,
+               on_round)
+    return run, base, trainable, masks, masks_np, opt, rng, s1_rounds
+
+
+def _record_ranks(history, rnd, masks_np, local_masks):
+    """Rank trajectory → trace (FedARA's per-round allocation decision)."""
+    if OBS.get_tracer().has_readers and masks_np:
+        history.record_ranks(rnd, masks_np,
+                             votes=MK.vote_fractions(local_masks))
+
+
+def _barrier_clock(history, rsp, pipe, fc, sel, encoded, down_per,
+                   protocol_s, drops=None, slows=None):
+    """Barrier-synchronous simulated clock: the round lasts as long as its
+    slowest surviving client (encoded bytes through its device's links plus
+    its compute time), plus the secagg protocol's ``protocol_s``.  Clients
+    with ``drops[k]`` never report; ``slows[k]`` multiplies compute time."""
+    enc_of = {e.cid: e for e in encoded}
+    costs = []
+    for k, cid in enumerate(sel):
+        if drops is not None and drops[k]:
+            continue
+        enc = enc_of[int(cid)]
+        costs.append(pipe.client_time(
+            int(cid), down_per, enc.nbytes,
+            DV.compute_s(int(cid), fc.device_profile, enc.n_steps,
+                         1.0 if slows is None else slows[k])))
+    if costs:
+        sc = sorted(costs)
+        rsp.set(cost_max=float(sc[-1]), cost_med=float(sc[len(sc) // 2]))
+    history.add_sim((max(costs) if costs else 0.0) + protocol_s)
+
+
+def _close_round(run: _Run, rsp, rnd, down, up, losses, base, trainable,
+                 masks, masks_np, staleness=0.0) -> None:
+    """Round close-out: the round's ``RoundLog``, the eval every
+    ``eval_every`` rounds and at the last, the recorder's ``end_round``,
+    then ``on_round`` — always the round's last call."""
+    fc, history = run.fc, run.history
+    live = int(MK.count_true(masks_np)) if masks_np else run.n_rank_units
+    n_dead = len(PR.dead_modules(masks_np)) if masks_np else 0
+    loss = float(np.mean(losses)) if losses else float("nan")
+    log = RoundLog(rnd, int(down), int(up), live, dead_modules=n_dead,
+                   trainable_params=PR.count_trainable(trainable),
+                   loss=loss, sim_time_s=history["sim_time_s"],
+                   staleness=staleness)
+    if (rnd + 1) % fc.eval_every == 0 or rnd == fc.rounds - 1:
+        log.acc = evaluate(run.model, base, trainable, masks, run.test, fc)
+        history["acc"].append((rnd, log.acc))
+    history.end_round(rsp, log, down, up)
+    if run.on_round:
+        run.on_round(rnd, log)
+
+
+def _finish_run(run: _Run, base, trainable, masks_np) -> dict:
+    """Run close-out: final accuracy, the DP guarantee spent (when an
+    accountant runs), wall time once the last update has landed, and the
+    final state."""
+    fc, history = run.fc, run.history
+    logs = history["rounds"]
+    history["final_acc"] = logs[-1].acc if logs else float("nan")
+    if run.accountant is not None:
+        history["dp"] = {"epsilon": run.accountant.epsilon(fc.dp_delta),
+                         "delta": fc.dp_delta,
+                         "noise_multiplier": fc.dp_noise_multiplier,
+                         "clip": fc.dp_clip}
+    jax.block_until_ready(trainable)            # stop the clock honestly
+    history["wall_s"] = time.perf_counter() - run.t0
+    history["base"] = base
+    history["trainable"] = trainable
+    history["masks"] = masks_np
+    history.finish()
+    return history
+
+
 def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
                   test: Dataset, fc: FedConfig,
                   on_round: Callable | None = None) -> dict:
@@ -419,28 +522,15 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
         from repro.fedsim import runner as FR   # lazy: fedsim imports us back
         return FR.run(model, strategy, parts, train, test, fc, on_round)
 
-    base, trainable, masks, masks_np, n_rank_units, opt, rng = \
-        _init_run(model, strategy, fc)
-    step_fn = CL.make_train_step(model, opt, fc.task)
     dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    run, base, trainable, masks, masks_np, opt, rng, s1_rounds = _start_run(
+        model, strategy, parts, train, test, fc, on_round, "seq", dev,
+        ("secagg_rounds", "dp_eps"), make_accountant(fc, len(parts)))
+    history = run.history
+    step_fn = CL.make_train_step(model, opt, fc.task)
     rp = round_programs(strategy, dev)
     pipe = PL.UploadPipeline(fc, strategy)
     private = SA.wants_private(fc)
-    accountant = make_accountant(fc, len(parts))
-
-    history = OBS.RunRecorder("seq", fc,
-                              extra_keys=("secagg_rounds", "dp_eps"))
-    logs: list[RoundLog] = history["rounds"]
-    t0 = time.perf_counter()
-
-    # SLoRA stage 1: sparse full-FT rounds before LoRA (baselines.SLoRA)
-    s1_rounds = (strategy.stage1_rounds(fc.rounds)
-                 if hasattr(strategy, "stage1_rounds") else 0)
-    if s1_rounds:
-        base, trainable = _run_stage1(model, strategy, base, trainable,
-                                      parts, train, fc, opt, rng, logs,
-                                      history, accountant)
-    trainable, masks = pin_params(trainable, masks, sharding=dev)
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
@@ -453,7 +543,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
         bc, masks, gate, _ = rp.place(bc, masks)
         down = down_per * len(sel)
 
-        results, local_masks, encoded = [], [], []
+        losses, local_masks, encoded = [], [], []
         for cid in sel:
             csp = history.begin_client(int(cid))
             idx = parts[cid]
@@ -468,7 +558,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
             if strategy.uses_masks():
                 lm = strategy.local_masks(rnd, params_k["adapters"],
                                           (grads_k or {}).get("adapters"),
-                                          n_rank_units)
+                                          run.n_rank_units)
                 local_masks.append(lm)
             # upload pruned by the *current* global mask (Alg. 1 line 28),
             # as a delta through the shared pipeline stages
@@ -477,7 +567,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
                                   n_steps=m["n_batches"])
             enc = pipe.encode(upd, masks_np)
             encoded.append(enc)
-            results.append((int(cid), m))
+            losses.append(m["loss"])
             csp.end(n_steps=m["n_batches"], up_bytes=enc.nbytes,
                     loss=m["loss"])
 
@@ -485,7 +575,7 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
             # ---- secagg / DP: the server only sees the field aggregate ---
             trainable, masks, masks_np, agg = _private_round(
                 strategy, bc, encoded, sel, masks, masks_np, fc, rnd,
-                history, accountant, pipe, rp.prune)
+                history, run.accountant, pipe, rp.prune)
             up = agg.up_bytes + sum(e.nbytes for e in encoded)
             down += agg.down_bytes
             protocol_s = agg.time_s
@@ -499,51 +589,13 @@ def run_federated(model, strategy, parts: list[np.ndarray], train: Dataset,
                 rp.prune)
             protocol_s = 0.0
 
-        # rank trajectory → trace (FedARA's per-round allocation decision)
-        if OBS.get_tracer().has_readers and masks_np:
-            history.record_ranks(rnd, masks_np,
-                                 votes=MK.vote_fractions(local_masks))
+        _record_ranks(history, rnd, masks_np, local_masks)
+        _barrier_clock(history, rsp, pipe, fc, sel, encoded, down_per,
+                       protocol_s)
+        _close_round(run, rsp, rnd, down, up, losses, base, trainable,
+                     masks, masks_np)
 
-        # ---- simulated wall clock: encoded bytes through per-device Links
-        # (one transfer per client, like the cohort runner, so seq-vs-cohort
-        # sim clocks differ by engine, not by transport-model disagreement)
-        enc_of = {e.cid: e for e in encoded}
-        costs = [pipe.client_time(
-            int(cid), down_per, enc_of[int(cid)].nbytes,
-            DV.compute_s(int(cid), fc.device_profile,
-                         enc_of[int(cid)].n_steps)) for cid in sel]
-        if costs:
-            sc = sorted(costs)
-            rsp.set(cost_max=float(sc[-1]), cost_med=float(sc[len(sc) // 2]))
-        history.add_sim((max(costs) if costs else 0.0) + protocol_s)
-
-        live = int(MK.count_true(masks_np)) if masks_np else n_rank_units
-        n_dead = (len(PR.dead_modules(masks_np)) if masks_np else 0)
-        tp = PR.count_trainable(trainable)
-        loss = float(np.mean([r[1]["loss"] for r in results]))
-        log = RoundLog(rnd, int(down), int(up), live, dead_modules=n_dead,
-                       trainable_params=tp, loss=loss,
-                       sim_time_s=history["sim_time_s"])
-        if (rnd + 1) % fc.eval_every == 0 or rnd == fc.rounds - 1:
-            log.acc = evaluate(model, base, trainable, masks, test, fc)
-            history["acc"].append((rnd, log.acc))
-        history.end_round(rsp, log, down, up)
-        if on_round:
-            on_round(rnd, log)
-
-    history["final_acc"] = logs[-1].acc if logs else float("nan")
-    if accountant is not None:
-        history["dp"] = {"epsilon": accountant.epsilon(fc.dp_delta),
-                         "delta": fc.dp_delta,
-                         "noise_multiplier": fc.dp_noise_multiplier,
-                         "clip": fc.dp_clip}
-    jax.block_until_ready(trainable)            # stop the clock honestly
-    history["wall_s"] = time.perf_counter() - t0
-    history["base"] = base
-    history["trainable"] = trainable
-    history["masks"] = masks_np
-    history.finish()
-    return history
+    return _finish_run(run, base, trainable, masks_np)
 
 
 def _take(gen, n):

@@ -140,16 +140,9 @@ def schedule(n_steps: np.ndarray, T: int, lanes: int, ndev: int = 1
 
 
 def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
-                 pad_clients_to: int, bucket: bool = False,
-                 ndev: int = 1) -> Cohort | None:
+                 pad_clients_to: int, ndev: int = 1) -> Cohort | None:
     """Materialize selected clients' local batches into a padded rectangle
     using the same RNG streams as the sequential oracle.
-
-    ``bucket=True`` re-buckets the step axis per round: instead of padding
-    every client to the global ``max_local_batches × local_epochs`` ceiling,
-    the rectangle's T is the next power of two ≥ this cohort's real maximum
-    step count — dirichlet-skewed cohorts stop paying for steps nobody runs,
-    and the pow-2 snap bounds distinct compiled shapes to log2(T_max).
 
     ``ndev > 1`` orders the slots by ``shard_order``, so that each device's
     shard of the cohort holds about as many steps as the others.
@@ -172,8 +165,6 @@ def build_cohort(train: Dataset, parts: list[np.ndarray], sel, fc, rnd: int,
         cids.append(int(cid))
     if not raw:
         return None
-    if bucket:
-        T = min(T, 1 << (max(len(bl) for bl in raw) - 1).bit_length())
     stacked, smask, nsteps = [], [], []
     for bl in raw:
         m = np.zeros(T, bool)
@@ -215,10 +206,10 @@ def stack_params(trainable: Any, n: int) -> Any:
 
 
 def make_step(model, opt, task: str = "cls"):
-    """One client's local step, shared by the cohort program's lanes and
-    ``make_local_phase``: ``step(base, masks, gate, params, opt_state,
-    batch) → (params', opt_state', grads, loss, metric, counts)``, the
-    oracle's step (``federated/client.py``) op for op."""
+    """One client's local step, run by the cohort program's lanes:
+    ``step(base, masks, gate, params, opt_state, batch) → (params',
+    opt_state', grads, loss, metric, counts)``, the oracle's step
+    (``federated/client.py``) op for op."""
     loss_fn = model.cls_loss if task == "cls" else model.lm_loss
     remat = model.cfg.train_remat
 
@@ -237,41 +228,6 @@ def make_step(model, opt, task: str = "cls"):
         return new_params, new_opt, g, loss, metric, counts
 
     return step
-
-
-def make_local_phase(model, opt, task: str = "cls"):
-    """One client's whole local-training phase as a scan over (padded)
-    batches — the inner loop of ``fused.make_fused_fn`` (vmapped inside a
-    scan over rounds).
-
-    ``local_phase(base, params0, masks, gate, bstack, smask) → (params,
-    grads, losses, metrics)``; a False ``smask`` step computes and then
-    discards, so real steps are structurally identical to the oracle's.
-    """
-    step = make_step(model, opt, task)
-
-    def local_phase(base, params0, masks, gate, bstack, smask):
-        opt0 = opt.init(params0)
-        g0 = jax.tree.map(jnp.zeros_like, params0)
-
-        def scan_step(carry, xs):
-            params, opt_state, grads = carry
-            batch, live = xs
-            new_params, new_opt, g, loss, metric, _ = step(
-                base, masks, gate, params, opt_state, batch)
-
-            def keep(n, o):
-                return jnp.where(live, n, o)
-            carry = (jax.tree.map(keep, new_params, params),
-                     jax.tree.map(keep, new_opt, opt_state),
-                     jax.tree.map(keep, g, grads))
-            return carry, (loss, metric)
-
-        (params, _, grads), (losses, metrics) = jax.lax.scan(
-            scan_step, (params0, opt0, g0), (bstack, smask))
-        return params, grads, losses, metrics
-
-    return local_phase
 
 
 def make_cohort_fn(model, opt, task: str = "cls", mesh=None):

@@ -24,7 +24,6 @@ link pricing), the same wire path the sequential oracle uses.
 from __future__ import annotations
 
 import heapq
-import time
 from typing import Callable
 
 import jax
@@ -32,8 +31,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs as OBS
-from repro.core import masks as MK
-from repro.core import pruning as PR
 from repro.data.synthetic import Dataset, batches
 from repro.federated import client as CL
 from repro.federated import devices as DV
@@ -44,10 +41,6 @@ from repro.fedsim import transport as T
 from repro.secagg import protocol as SA
 
 device_of = DV.device_of          # shared client→device-class assignment
-
-
-def _compute_s(cid: int, fc, n_batches: int, slow: float = 1.0) -> float:
-    return DV.compute_s(cid, fc.device_profile, n_batches, slow)
 
 
 def _event_rng(fc) -> np.random.Generator:
@@ -76,49 +69,27 @@ def run(model, strategy, parts, train, test, fc,
 
 def run_cohort(model, strategy, parts, train, test, fc,
                on_round: Callable | None = None) -> dict:
-    if getattr(fc, "fuse_rounds", 1) > 1:
-        # fused fast path: one XLA program per K rounds (fedsim/fused.py);
-        # anything needing host work between rounds falls back to the eager
-        # loop below, with the reason on the trace
-        from repro.fedsim import fused as FU
-        ok, why = FU.eligible(fc, strategy, parts)
-        if ok:
-            return FU.run_fused(model, strategy, parts, train, test, fc,
-                                on_round)
-        OBS.get_tracer().event("fused_fallback", reason=why)
-    base, trainable, masks, masks_np, n_rank_units, opt, rng = \
-        SV._init_run(model, strategy, fc)
-    step_fn = CL.make_train_step(model, opt, fc.task)     # ragged fallback
     mesh = CH.cohort_mesh()
+    # broadcast state replicated on the mesh, its per-client copies split
+    # over it, the same every round (see SV.round_programs)
+    rep = jax.sharding.NamedSharding(mesh, P())
+    run, base, trainable, masks, masks_np, opt, rng, s1_rounds = \
+        SV._start_run(model, strategy, parts, train, test, fc, on_round,
+                      "cohort", rep, ("secagg_rounds", "dp_eps"),
+                      SV.make_accountant(fc, len(parts)))
+    history = run.history
+    step_fn = CL.make_train_step(model, opt, fc.task)     # ragged fallback
     cohort_fn = CH.make_cohort_fn(model, opt, fc.task, mesh=mesh)
     ndev = len(jax.devices())
     cpr = min(fc.clients_per_round, len(parts))
     c_pad = -(-cpr // ndev) * ndev                        # shardable cohort
     lanes = CH.lane_count(parts, fc, c_pad, ndev)
-    # broadcast state replicated on the mesh, its per-client copies split
-    # over it, the same every round (see SV.round_programs)
-    rep = jax.sharding.NamedSharding(mesh, P())
     rp = SV.round_programs(strategy, rep, slots=c_pad,
                            slot_sharding=jax.sharding.NamedSharding(
                                mesh, P("clients")))
-
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
     private = SA.wants_private(fc)
-    accountant = SV.make_accountant(fc, len(parts))
-
-    history = OBS.RunRecorder("cohort", fc,
-                              extra_keys=("secagg_rounds", "dp_eps"))
-    logs: list[SV.RoundLog] = history["rounds"]
-    t0 = time.perf_counter()
-
-    s1_rounds = (strategy.stage1_rounds(fc.rounds)
-                 if hasattr(strategy, "stage1_rounds") else 0)
-    if s1_rounds:
-        base, trainable = SV._run_stage1(model, strategy, base, trainable,
-                                         parts, train, fc, opt, rng, logs,
-                                         history, accountant)
-    trainable, masks = SV.pin_params(trainable, masks, sharding=rep)
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
@@ -143,7 +114,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
 
         # ---- local phase: one dispatch for the whole cohort --------------
         cohort = CH.build_cohort(train, parts, active, fc, rnd, c_pad,
-                                 bucket=fc.rebucket, ndev=ndev)
+                                 ndev=ndev)
         pc = gc = lc = mc = avg = None
         cohort_idx = {}
         if cohort is not None:
@@ -195,7 +166,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
                 lambda p, b: np.asarray(p, np.float32)
                 - np.asarray(b, np.float32), pc_host, bc_host)
 
-        results, local_masks, encoded = [], [], []
+        losses, local_masks, encoded = [], [], []
         up = 0
         for cid in active:
             csp = history.begin_client(cid)
@@ -233,7 +204,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
                 isp = tr.begin("importance", kind="stage")
                 lm = strategy.local_masks(
                     rnd, params_k["adapters"],
-                    (grads_k or {}).get("adapters"), n_rank_units)
+                    (grads_k or {}).get("adapters"), run.n_rank_units)
                 isp.end()
                 local_masks.append(lm)
             upd = PL.ClientUpdate(int(cid), delta_k,
@@ -244,7 +215,7 @@ def run_cohort(model, strategy, parts, train, test, fc,
             esp.end()
             up += enc.nbytes
             encoded.append(enc)
-            results.append((w, m))
+            losses.append(m["loss"])
             csp.end(n_steps=m["n_batches"], up_bytes=enc.nbytes,
                     loss=m["loss"])
         usp.end()
@@ -258,11 +229,11 @@ def run_cohort(model, strategy, parts, train, test, fc,
             # still pays — and records — the advertise/share phases)
             trainable, masks, masks_np, agg = SV._private_round(
                 strategy, bc, encoded, sel, masks, masks_np, fc, rnd,
-                history, accountant, pipe, rp.prune)
+                history, run.accountant, pipe, rp.prune)
             up = agg.up_bytes + sum(e.nbytes for e in encoded)
             down += agg.down_bytes
             protocol_s = agg.time_s
-        elif results:
+        elif encoded:
             if pipe.codec is None and cohort is not None \
                     and not cohort.fallback:
                 # identity wire: the on-device psum FedAvg equals the
@@ -274,55 +245,13 @@ def run_cohort(model, strategy, parts, train, test, fc,
                 strategy, trainable, local_masks, masks, masks_np, rnd,
                 rp.prune)
 
-        # rank trajectory → trace (FedARA's per-round allocation decision)
-        if tr.has_readers and masks_np:
-            history.record_ranks(rnd, masks_np,
-                                 votes=MK.vote_fractions(local_masks))
+        SV._record_ranks(history, rnd, masks_np, local_masks)
+        SV._barrier_clock(history, rsp, pipe, fc, sel, encoded, down_per,
+                          protocol_s, drops, slows)
+        SV._close_round(run, rsp, rnd, down, up, losses, base, trainable,
+                        masks, masks_np)
 
-        # ---- simulated wall clock (barrier = slowest surviving client) --
-        enc_of = {e.cid: e for e in encoded}
-        costs = []
-        for k, cid in enumerate(sel):
-            if drops[k]:
-                continue
-            cid = int(cid)
-            costs.append(pipe.client_time(
-                cid, down_per, enc_of[cid].nbytes,
-                _compute_s(cid, fc, enc_of[cid].n_steps, slows[k])))
-        round_s = (max(costs) if costs else 0.0) + protocol_s
-        if costs:
-            sc = sorted(costs)
-            rsp.set(cost_max=float(sc[-1]), cost_med=float(sc[len(sc) // 2]))
-        history.add_sim(round_s)
-
-        live = int(MK.count_true(masks_np)) if masks_np else n_rank_units
-        n_dead = len(PR.dead_modules(masks_np)) if masks_np else 0
-        loss = (float(np.mean([r[1]["loss"] for r in results]))
-                if results else float("nan"))
-        log = SV.RoundLog(rnd, int(down), int(up), live,
-                          dead_modules=n_dead,
-                          trainable_params=PR.count_trainable(trainable),
-                          loss=loss, sim_time_s=history["sim_time_s"])
-        if (rnd + 1) % fc.eval_every == 0 or rnd == fc.rounds - 1:
-            log.acc = SV.evaluate(model, base, trainable, masks, test, fc)
-            history["acc"].append((rnd, log.acc))
-        history.end_round(rsp, log, down, up)
-        if on_round:
-            on_round(rnd, log)
-
-    history["final_acc"] = logs[-1].acc if logs else float("nan")
-    if accountant is not None:
-        history["dp"] = {"epsilon": accountant.epsilon(fc.dp_delta),
-                         "delta": fc.dp_delta,
-                         "noise_multiplier": fc.dp_noise_multiplier,
-                         "clip": fc.dp_clip}
-    jax.block_until_ready(trainable)
-    history["wall_s"] = time.perf_counter() - t0
-    history["base"] = base
-    history["trainable"] = trainable
-    history["masks"] = masks_np
-    history.finish()
-    return history
+    return SV._finish_run(run, base, trainable, masks_np)
 
 
 # ---------------------------------------------------------------------------
@@ -331,25 +260,15 @@ def run_cohort(model, strategy, parts, train, test, fc,
 
 def run_async(model, strategy, parts, train, test, fc,
               on_round: Callable | None = None) -> dict:
-    base, trainable, masks, masks_np, n_rank_units, opt, rng = \
-        SV._init_run(model, strategy, fc)
-    step_fn = CL.make_train_step(model, opt, fc.task)
     dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    run, base, trainable, masks, masks_np, opt, rng, s1_rounds = \
+        SV._start_run(model, strategy, parts, train, test, fc, on_round,
+                      "async", dev, ("events",))
+    history = run.history
+    step_fn = CL.make_train_step(model, opt, fc.task)
     rp = SV.round_programs(strategy, dev)
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
-
-    history = OBS.RunRecorder("async", fc, extra_keys=("events",))
-    logs: list[SV.RoundLog] = history["rounds"]
-    t0 = time.perf_counter()
-
-    s1_rounds = (strategy.stage1_rounds(fc.rounds)
-                 if hasattr(strategy, "stage1_rounds") else 0)
-    if s1_rounds:
-        base, trainable = SV._run_stage1(model, strategy, base, trainable,
-                                         parts, train, fc, opt, rng, logs,
-                                         history)
-    trainable, masks = SV.pin_params(trainable, masks, sharding=dev)
 
     buffer_k = fc.buffer_k or min(fc.clients_per_round, len(parts))
     concurrency = fc.async_concurrency or 2 * buffer_k
@@ -373,7 +292,8 @@ def run_async(model, strategy, parts, train, test, fc,
         n_b = _n_local_batches(len(parts[cid]), fc)
         link = T.link_for(device_of(cid))
         # upload size is only known post-encode; model it as symmetric
-        finish = (now + link.transfer_s(down) + _compute_s(cid, fc, n_b, slow)
+        finish = (now + link.transfer_s(down)
+                  + DV.compute_s(cid, fc.device_profile, n_b, slow)
                   + link.transfer_s(down))
         if not dropped:
             stash[seq_no] = (bc, bc_masks, masks_np, gate, version)
@@ -426,44 +346,22 @@ def run_async(model, strategy, parts, train, test, fc,
                 for _, pk, gk, *_ in buffer:
                     local_masks.append(strategy.local_masks(
                         agg, pk["adapters"], (gk or {}).get("adapters"),
-                        n_rank_units))
+                        run.n_rank_units))
             trainable, masks, masks_np = SV._arbitrate(
                 strategy, trainable, local_masks, masks, masks_np, agg,
                 rp.prune)
-            if OBS.get_tracer().has_readers and masks_np:
-                history.record_ranks(agg, masks_np,
-                                     votes=MK.vote_fractions(local_masks))
-            live = (int(MK.count_true(masks_np)) if masks_np
-                    else n_rank_units)
-            n_dead = len(PR.dead_modules(masks_np)) if masks_np else 0
+            SV._record_ranks(history, agg, masks_np, local_masks)
             history.set_sim(now)
-            log = SV.RoundLog(
-                agg, int(pend_down), int(pend_up), live,
-                dead_modules=n_dead,
-                trainable_params=PR.count_trainable(trainable),
-                loss=float(np.mean([b[3]["loss"] for b in buffer])),
-                sim_time_s=now,
+            SV._close_round(
+                run, rsp, agg, pend_down, pend_up,
+                [b[3]["loss"] for b in buffer], base, trainable, masks,
+                masks_np,
                 staleness=float(np.mean([b[0].staleness for b in buffer])))
-            b_down, b_up = pend_down, pend_up
             pend_down = pend_up = 0
-            if (agg + 1) % fc.eval_every == 0 or agg == fc.rounds - 1:
-                log.acc = SV.evaluate(model, base, trainable, masks, test,
-                                      fc)
-                history["acc"].append((agg, log.acc))
-            history.end_round(rsp, log, b_down, b_up)
-            if on_round:
-                on_round(agg, log)
             buffer.clear()
             version += 1
             agg += 1
 
     # in-flight broadcasts were transmitted even if never aggregated
     history.inflight_comm(pend_down, pend_up)
-    history["final_acc"] = logs[-1].acc if logs else float("nan")
-    jax.block_until_ready(trainable)
-    history["wall_s"] = time.perf_counter() - t0
-    history["base"] = base
-    history["trainable"] = trainable
-    history["masks"] = masks_np
-    history.finish()
-    return history
+    return SV._finish_run(run, base, trainable, masks_np)

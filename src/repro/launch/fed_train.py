@@ -53,19 +53,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--runner", default="seq",
                     choices=["seq", "cohort", "async"])
-    ap.add_argument("--fuse-rounds", type=int, default=1, metavar="K",
-                    help="cohort: scan K rounds per XLA dispatch (1 ≡ "
-                         "eager loop; >1 takes the fused fast path when "
-                         "codec/privacy/ragged clients permit, else falls "
-                         "back with the reason on the trace)")
     ap.add_argument("--opt-state-dtype", default="float32",
                     choices=["float32", "bfloat16", "int8"],
                     help="adam moment storage (bf16 halves per-client "
                          "optimizer state; int8 quarters it)")
-    ap.add_argument("--rebucket", action="store_true",
-                    help="cohort: re-bucket each round's step axis to the "
-                         "next pow-2 of the cohort's real max local steps "
-                         "(cuts padding waste on skewed partitions)")
     ap.add_argument("--codec", default="identity",
                     choices=["identity", "int8", "topk", "signsgd",
                              "powersgd"])
@@ -139,9 +130,7 @@ def main(argv=None):
     fc = FedConfig(rounds=args.rounds,
                    clients_per_round=args.clients_per_round, seed=args.seed,
                    runner=args.runner, codec=args.codec,
-                   fuse_rounds=args.fuse_rounds,
                    opt_state_dtype=args.opt_state_dtype,
-                   rebucket=args.rebucket,
                    powersgd_rank=args.powersgd_rank,
                    straggler=args.straggler, dropout=args.dropout,
                    buffer_k=args.buffer_k, event_seed=args.event_seed,

@@ -52,11 +52,14 @@ def _run(setup, runner, strategy="fedara", **fc_kw):
 # cohort ↔ sequential parity
 # ---------------------------------------------------------------------------
 
-def test_cohort_matches_sequential_oracle(setup):
-    """A MINI FedARA run: same per-round losses (within fp tolerance from
-    batched-vs-looped XLA fusion), identical masks and byte accounting."""
-    h_seq = _run(setup, "seq")
-    h_coh = _run(setup, "cohort")
+@pytest.mark.parametrize("strategy", ["fedara", "fedlora", "ffa_lora"])
+def test_cohort_matches_sequential_oracle(setup, strategy):
+    """A MINI run: same per-round losses (within fp tolerance from
+    batched-vs-looped XLA fusion), identical masks and byte accounting.
+    FedARA re-prunes every round; FFA-LoRA freezes A through the
+    optimizer gate."""
+    h_seq = _run(setup, "seq", strategy=strategy)
+    h_coh = _run(setup, "cohort", strategy=strategy)
     for a, b in zip(h_seq["rounds"], h_coh["rounds"]):
         assert a.down_bytes == b.down_bytes
         assert a.up_bytes == b.up_bytes

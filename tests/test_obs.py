@@ -404,7 +404,8 @@ def _traced_run(setup, path, **fc_kw):
         setattr(strat, k, v)
     model = Model(cfg, peft=strat.peft, unroll=True)
     fc = FedConfig(rounds=rounds, clients_per_round=3, batch_size=16,
-                   max_local_batches=2, eval_every=rounds, lr=3e-3, **fc_kw)
+                   max_local_batches=2, lr=3e-3,
+                   eval_every=fc_kw.pop("eval_every", rounds), **fc_kw)
     try:
         obs.configure(path, meta=obs.provenance({"cmd": "test"}))
         h = run_federated(model, strat, parts, train, test, fc)
@@ -531,7 +532,18 @@ def fedara_masks_trace(setup, tmp_path_factory):
     return h, E.read_jsonl(path)
 
 
-@pytest.mark.parametrize("trace", ["fedara_trace", "fedara_masks_trace"])
+@pytest.fixture(scope="module")
+def fedlora_eval_trace(setup, tmp_path_factory):
+    """A traced 5-round FedLoRA cohort run that evaluates after rounds 1, 3
+    and 4: eval boundaries inside the round loop, not only at its end."""
+    path = str(tmp_path_factory.mktemp("fedlora_eval") / "fedlora.jsonl")
+    h = _traced_run(setup, path, runner="cohort", strategy="fedlora",
+                    rounds=5, eval_every=2)
+    return h, E.read_jsonl(path)
+
+
+@pytest.mark.parametrize("trace", ["fedara_trace", "fedara_masks_trace",
+                                   "fedlora_eval_trace"])
 def test_compile_flat_after_first_round(trace, request):
     """Compile-span accounting on a traced cohort run
     shows zero new compilations after round 1.  Rounds are 0-indexed in
@@ -545,6 +557,8 @@ def test_compile_flat_after_first_round(trace, request):
     if trace == "fedara_masks_trace":
         live = [log.live_ranks for log in h["rounds"]]
         assert live[0] > live[1] > live[2], live
+    if trace == "fedlora_eval_trace":
+        assert [r for r, _ in h["acc"]] == [1, 3, 4]
     cs = P.compile_stats(events)
     assert cs["after_first_round"] == 0, cs["by_round"]
     assert all(rnd == 0 for rnd in cs["by_round"]), cs["by_round"]
