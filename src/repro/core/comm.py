@@ -120,6 +120,15 @@ def prune_tree(adapters: Any, masks: Any | None):
     return walk(adapters, masks)
 
 
+def sq_sum(adapters: Any, masks: Any | None):
+    """Sum of squares of what ``pack`` transmits, in float32, as traceable
+    jax: every rank module's live ranks (``prune_tree`` zeroes the rest)."""
+    ad = prune_tree(adapters, masks)
+    return sum(jnp.sum(jnp.square(jnp.asarray(mod[k], jnp.float32)))
+               for _, mod, _ in _iter_modules(ad, None)
+               for k in ("A", "B", "E") if k in mod)
+
+
 def pack_int8(adapters: Any, masks: Any | None) -> tuple[np.ndarray, float]:
     """Quantized wire format (QLoRA-adjacent, paper §VIII): symmetric int8
     per-tensor quantization of the surviving-rank payload — 4× fewer bytes

@@ -50,6 +50,11 @@ class Strategy:
     def local_masks(self, rnd: int, adapters, grads, n_modules_ranks: int):
         return None
 
+    def device_importance(self) -> str | None:
+        """The importance method of ``local_masks`` when a device program
+        can score each client alone (``IMP.score_flat``), else None."""
+        return None
+
     def arbitrate(self, rnd: int, local_masks, prev_global):
         return prev_global
 
@@ -114,6 +119,15 @@ class FedARA(Strategy):
             ema_state=self._ema)
         b = self.budget(rnd, n_rank_units)
         return MK.generate_local_masks(scores, b)
+
+    def device_importance(self) -> str | None:
+        # Sensitivity's EMA runs across clients, so only the host has it
+        return None if self.importance == IMP.SENSITIVITY else self.importance
+
+    def masks_from_scores(self, rnd: int, flat, layout, n_rank_units: int):
+        """``local_masks`` from one client's scores already computed
+        (``IMP.score_flat``), laid out as ``layout``."""
+        return MK.top_budget(flat, layout, self.budget(rnd, n_rank_units))
 
     def arbitrate(self, rnd: int, local_masks, prev_global):
         if not local_masks:

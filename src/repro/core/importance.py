@@ -10,9 +10,13 @@ with four leaf scores:
     Mixed        I(w) = |w · ∂ℓ/∂w|
     Sensitivity  AdaLoRA-style EMA of |w·g| (≈1.3× compute, Table I)
 
-Scores are computed host-side per round over the (tiny) adapter tree; per-
-expert adapters average over the expert axis because the rank mask belongs to
-the insertion position (layer, component), not to individual experts.
+Scores are computed per client over the (tiny) adapter tree: on the host
+(``score_tree``) by the sequential and async runners, and for the cohort's
+slots on the device (``score_flat``, in the cohort runner's upload program,
+federated/server.py ``round_programs``); both use one definition of the
+maths.  Per-expert adapters average over the expert axis because the rank
+mask belongs to the insertion position (layer, component), not to
+individual experts.
 """
 
 from __future__ import annotations
@@ -20,7 +24,10 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.pytree import flatten_with_paths
 
 MAG, GRAD, MIXED, SENSITIVITY = "mag", "grad", "mixed", "sensitivity"
 
@@ -33,76 +40,112 @@ def _is_module(x) -> bool:
     return isinstance(x, dict) and "A" in x and "B" in x
 
 
+def reads_grads(method: str) -> bool:
+    return method != MAG
+
+
 def _leaf_score(w, g, method: str):
     if method == MAG:
-        return np.abs(w)
+        return abs(w)
     if method == GRAD:
-        return np.abs(g)
+        return abs(g)
     if method in (MIXED, SENSITIVITY):
-        return np.abs(w * g)
+        return abs(w * g)
     raise ValueError(method)
 
 
 def _module_score(mod: dict, grads: dict | None, method: str,
-                  n_experts: int) -> np.ndarray:
-    """Returns (lead..., r) float score — per-expert axis averaged away."""
-    a, b = _np(mod["A"]), _np(mod["B"])
-    ga = _np(grads["A"]) if grads else np.zeros_like(a)
-    gb = _np(grads["B"]) if grads else np.zeros_like(b)
-    sa = _leaf_score(a, ga, method).mean(-1)          # (lead..., r)
-    sb = _leaf_score(b, gb, method).mean(-2)          # (lead..., r)
-    score = sa + sb
+                  n_experts: int):
+    """Returns (lead..., r) float32 score — per-expert axis averaged away.
+    numpy leaves give numpy, jax leaves (traced) give jax."""
+    xp = np if isinstance(mod["A"], np.ndarray) else jnp
+
+    def leaf(k):
+        w = xp.asarray(mod[k], xp.float32)
+        g = xp.asarray(grads[k], xp.float32) if grads else xp.zeros_like(w)
+        return _leaf_score(w, g, method)
+
+    score = leaf("A").mean(-1) + leaf("B").mean(-2)     # (lead..., r)
     if "E" in mod:
-        e = _np(mod["E"])
-        ge = _np(grads["E"]) if grads else np.zeros_like(e)
-        score = score + _leaf_score(e, ge, method)
+        score = score + leaf("E")
     # average the expert axis into the (layer, component) mask granularity
     if n_experts and score.ndim >= 2 and score.shape[-2] == n_experts:
         score = score.mean(-2)
     return score
 
 
+def _walk(ad, gr, prev, score):
+    """Mask-structured tree of ``score(module, its grads, prev)`` over the
+    adapters' rank modules, ``gr`` and ``prev`` walked alongside
+    (bottleneck adapters have no ranks)."""
+    if _is_module(ad):
+        return score(ad, gr, prev)
+    if isinstance(ad, dict):
+        out = {}
+        for k, v in ad.items():
+            if isinstance(v, dict) and "down" in v:
+                continue
+            r = _walk(v, gr.get(k) if isinstance(gr, dict) else None,
+                      prev.get(k) if isinstance(prev, dict) else None, score)
+            if r is not None:
+                out[k] = r
+        return out or None
+    return None
+
+
 def score_tree(adapters: Any, grads: Any | None, method: str = MAG,
                n_experts: int = 0, ema_state: Any | None = None,
                ema_beta: float = 0.85):
-    """Mask-structured tree of importance scores.
+    """Mask-structured tree of importance scores, on the host.
 
     Returns (scores, new_ema_state).  ``ema_state`` is used only by the
     Sensitivity method (AdaLoRA's smoothed sensitivity).
     """
-    new_ema: dict = {}
+    def score(mod, gr, prev):
+        s = _module_score({k: _np(v) for k, v in mod.items()},
+                          {k: _np(v) for k, v in gr.items()} if gr else None,
+                          method, n_experts)
+        if method == SENSITIVITY:
+            prev = prev if isinstance(prev, np.ndarray) else np.zeros_like(s)
+            s = ema_beta * prev + (1 - ema_beta) * s
+        return s
 
-    def walk(ad, gr, ema, path):
-        if _is_module(ad):
-            s = _module_score(ad, gr, method, n_experts)
-            if method == SENSITIVITY:
-                prev = ema if isinstance(ema, np.ndarray) else np.zeros_like(s)
-                s = ema_beta * prev + (1 - ema_beta) * s
-                new_ema[path] = s
-            return s
-        if isinstance(ad, dict):
-            out = {}
-            for k, v in ad.items():
-                if isinstance(v, dict) and "down" in v:   # bottleneck: no ranks
-                    continue
-                r = walk(v, (gr or {}).get(k) if isinstance(gr, dict) else None,
-                         (ema or {}).get(k) if isinstance(ema, dict) else None,
-                         f"{path}.{k}")
-                if r is not None:
-                    out[k] = r
-            return out or None
-        return None
-
-    scores = walk(adapters, grads, ema_state, "") or {}
+    scores = _walk(adapters, grads, ema_state, score) or {}
     if method == SENSITIVITY:
-        # rebuild nested ema from scores (same structure)
+        # the smoothed scores are the next client's EMA state
         return scores, scores
     return scores, ema_state
 
 
+def score_flat(adapters: Any, grads: Any | None, method: str = MAG,
+               n_experts: int = 0) -> jax.Array:
+    """``flat_concat(score_tree(...)[0])[0]`` as traceable jax: one client's
+    scores in ``score_layout``'s order.  Every method but Sensitivity,
+    whose EMA runs across clients on the host."""
+    if method == SENSITIVITY:
+        raise ValueError("sensitivity scores carry an EMA across clients: "
+                         "score_tree computes them on the host")
+    leaves = jax.tree.leaves(_walk(
+        adapters, grads, None,
+        lambda mod, gr, _: _module_score(mod, gr, method, n_experts)))
+    if not leaves:
+        return jnp.zeros((0,), jnp.float32)
+    return jnp.concatenate([s.reshape(-1) for s in leaves])
+
+
+def score_layout(adapters: Any, n_experts: int = 0
+                 ) -> list[tuple[str, tuple]]:
+    """``flat_concat``'s layout of the adapters' scores (arrays or
+    shapes): where each module's ranks sit in ``score_flat``'s vector."""
+    tree = jax.eval_shape(lambda ad: _walk(
+        ad, None, None,
+        lambda mod, gr, _: _module_score(mod, gr, MAG, n_experts)) or {},
+        adapters)
+    return [(p, s.shape) for p, s in flatten_with_paths(tree)]
+
+
 def flat_concat(score_tree_: Any) -> tuple[np.ndarray, list[tuple[str, tuple]]]:
     """Flatten a mask-structured tree → (flat vector, [(path, shape)])."""
-    from repro.pytree import flatten_with_paths
     items = flatten_with_paths(score_tree_,
                                is_leaf=lambda x: isinstance(x, np.ndarray))
     vecs, layout = [], []

@@ -16,7 +16,12 @@ from repro.core import importance as IMP
 
 def generate_local_masks(scores: Any, budget: int) -> Any:
     """Top-``budget`` triplets across the whole model → boolean mask tree."""
-    flat, layout = IMP.flat_concat(scores)
+    return top_budget(*IMP.flat_concat(scores), budget)
+
+
+def top_budget(flat: np.ndarray, layout: list, budget: int) -> Any:
+    """``generate_local_masks`` of scores already flat, in
+    ``IMP.flat_concat``'s order and ``layout``."""
     n = flat.size
     if n == 0:
         return {}

@@ -41,6 +41,7 @@ import numpy as np
 from repro import obs as OBS
 from repro import optim as OPT
 from repro.core import comm as COMM
+from repro.core import importance as IMP
 from repro.core import masks as MK
 from repro.core import pruning as PR
 from repro.data.synthetic import Dataset, batches
@@ -191,12 +192,13 @@ class RoundPrograms(NamedTuple):
     """The server's per-round work on the adapter tree (``round_programs``)."""
     prune: Callable     # (trainable, masks_np) → (trainable, masks)
     place: Callable     # (bc, masks) → (bc, masks, gate, stacked)
+    upload: Callable | None     # (pc, gc, bc, masks) → (scores, norms)
 
 
 def round_programs(strategy, sharding, slots: int = 0,
                    slot_sharding=None) -> RoundPrograms:
-    """One run's round-loop work on the adapter tree as two compiled
-    programs, each one dispatch where eager code took one per leaf.
+    """One run's round-loop work on the adapter tree as compiled programs,
+    each one dispatch where eager code took one per leaf.
 
     ``prune`` zeroes the trainable adapters' masked-out ranks (CommPru,
     ``COMM.prune_tree``) and returns the masks on the device: before the
@@ -205,11 +207,21 @@ def round_programs(strategy, sharding, slots: int = 0,
     and, for ``slots`` > 0, the cohort program's per-client copies
     (``cohort.stack_params``) split over ``slot_sharding``.
 
+    For ``slots`` > 0, ``upload`` reduces the cohort program's per-slot
+    params ``pc`` (and grads ``gc``, where the importance method reads
+    them, else None) against the broadcast ``bc`` to what the host's
+    upload stage reads, both split over ``slot_sharding``: each slot's
+    importance scores, ``(slots, R)`` in ``IMP.score_layout``'s order
+    (None where ``strategy.device_importance()`` is), and the L2 norm of
+    its identity wire ``pc − bc`` (``transport.wire_norm``).
+
     Mask values are arguments, so a round whose masks changed compiles
-    nothing.  ``prune`` takes the host masks and ``place`` device masks
-    (``prune``'s, or pinned ones), each always in one placement, and
-    every output lands on the same sharding every round.
+    nothing.  ``prune`` takes the host masks and ``place`` and ``upload``
+    device masks (``prune``'s, or pinned ones), each always in one
+    placement, and every output lands on the same sharding every round.
     """
+    method = strategy.device_importance()
+
     def prune(trainable, masks_np):
         return dict(trainable, adapters=COMM.prune_tree(
             trainable["adapters"], masks_np)), masks_np
@@ -218,10 +230,23 @@ def round_programs(strategy, sharding, slots: int = 0,
         stacked = stack_params(bc, slots) if slots else None
         return bc, masks, strategy.optimizer_gate(bc, masks), stacked
 
+    def upload(pc, gc, bc, masks):
+        def one(p, g):
+            norm = T.wire_norm(jax.tree.map(
+                lambda x, b: x.astype(jnp.float32) - b.astype(jnp.float32),
+                p, bc), masks)
+            if method is None:
+                return None, norm
+            return IMP.score_flat(p["adapters"],
+                                  g["adapters"] if g is not None else None,
+                                  method, strategy.n_experts), norm
+        return jax.vmap(one)(pc, gc)
+
     return RoundPrograms(
         jax.jit(prune, out_shardings=sharding),
         jax.jit(place, out_shardings=(sharding, sharding, sharding,
-                                      slot_sharding)))
+                                      slot_sharding)),
+        jax.jit(upload, out_shardings=slot_sharding) if slots else None)
 
 
 def _arbitrate(strategy, trainable, local_masks, masks, masks_np, rnd,

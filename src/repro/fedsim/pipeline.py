@@ -49,7 +49,8 @@ from repro.secagg import dp as DP
 class ClientUpdate:
     """What a producer hands the pipeline: one client's round contribution."""
     cid: int
-    delta: Any                      # f32 delta tree (global-state structure)
+    delta: Any                      # f32 delta tree (global-state
+                                    # structure); None for encode_reduced
     weight: float                   # aggregation weight (data size)
     votes: Any | None = None        # local rank-mask tree (FedArb votes)
     n_steps: int = 0                # local batches run (compute pricing)
@@ -60,8 +61,9 @@ class ClientUpdate:
 class EncodedUpdate:
     """A ClientUpdate after the wire stages: what the server aggregates."""
     cid: int
-    wire: np.ndarray                # decoded (post-codec, post-snap) wire
+    wire: np.ndarray | None         # decoded (post-codec, post-snap) wire
     delta: Any                      # the decoded delta *tree* (same content)
+                                    # (both None from encode_reduced)
     nbytes: int                     # exact upload bytes (0 under secagg —
                                     # the protocol phases price the upload)
     weight: float
@@ -283,6 +285,31 @@ class UploadPipeline:
                     + T.mask_wire_bytes(masks_np)
         if getattr(fc, "secagg", "off") != "off":
             nbytes = 0        # the protocol's masked phase prices the upload
+        self._account(upd.cid, nbytes, norm, clipped)
+        d_tree = self.unflatten(dec, upd.delta, masks_np)
+        return EncodedUpdate(
+            cid=upd.cid, wire=dec, delta=d_tree, nbytes=nbytes,
+            weight=upd.weight, votes=upd.votes, clipped=clipped, norm=norm,
+            n_steps=upd.n_steps, staleness=upd.staleness)
+
+    def encode_reduced(self, upd: ClientUpdate, norm: float, nbytes: int
+                       ) -> EncodedUpdate:
+        """An identity-wire update whose delta a device program reduced to
+        its wire's L2 ``norm`` (``transport.wire_norm``), with ``nbytes``
+        as ``encode`` prices its wire (the strategy's ``comm_up``, which
+        reads shapes and masks): the counters and ``encode`` event of
+        ``encode``, and no wire or delta tree, for a round that aggregates
+        on the device."""
+        assert self.codec is None and self.field_spec is None \
+            and getattr(self.fc, "dp_clip", 0.0) <= 0
+        self._account(upd.cid, nbytes, norm, False)
+        return EncodedUpdate(
+            cid=upd.cid, wire=None, delta=None, nbytes=nbytes,
+            weight=upd.weight, votes=upd.votes, norm=norm,
+            n_steps=upd.n_steps, staleness=upd.staleness)
+
+    def _account(self, cid, nbytes: int, norm: float, clipped: bool) -> None:
+        fc = self.fc
         m = OBS.get_metrics()
         if m.enabled:
             m.counter("pipeline.up_bytes", codec=fc.codec,
@@ -293,20 +320,15 @@ class UploadPipeline:
                 m.counter("dp.clip_events", stage=self.stage).inc()
             ef_norm = 0.0
             if self.codec is not None:
-                ef_norm = float(np.linalg.norm(self._resid[upd.cid]))
+                ef_norm = float(np.linalg.norm(self._resid[cid]))
                 m.histogram("pipeline.ef_residual_norm",
                             codec=fc.codec).observe(ef_norm)
             # per-update encode event: the EF-residual stream the health
             # monitor watches for codec blowup (plus clip/byte forensics)
             OBS.get_tracer().event(
-                "encode", cid=int(upd.cid), norm=float(norm),
+                "encode", cid=int(cid), norm=float(norm),
                 ef_norm=ef_norm, clipped=bool(clipped),
                 nbytes=int(nbytes), stage=self.stage)
-        d_tree = self.unflatten(dec, upd.delta, masks_np)
-        return EncodedUpdate(
-            cid=upd.cid, wire=dec, delta=d_tree, nbytes=nbytes,
-            weight=upd.weight, votes=upd.votes, clipped=clipped, norm=norm,
-            n_steps=upd.n_steps, staleness=upd.staleness)
 
     # ---- link pricing ------------------------------------------------------
 

@@ -18,7 +18,11 @@ stream), event times / dropout / stragglers from ``fc.event_seed`` — so one
 (seed, event_seed) pair reproduces the identical history and event log.
 Both runners emit ``fedsim.pipeline.ClientUpdate`` deltas through the shared
 delta pipeline (flatten → clip → codec → error feedback → byte accounting →
-link pricing), the same wire path the sequential oracle uses.
+link pricing), the same wire path the sequential oracle uses.  A cohort
+round that aggregates on the device (identity wire, no privacy, no
+sub-batch client) builds no delta on the host: the upload program
+(``server.round_programs``) hands it each slot's importance scores and wire
+norm, and ``UploadPipeline.encode_reduced`` accounts for the upload.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs as OBS
+from repro.core import importance as IMP
 from repro.data.synthetic import Dataset, batches
 from repro.federated import client as CL
 from repro.federated import devices as DV
@@ -90,6 +95,12 @@ def run_cohort(model, strategy, parts, train, test, fc,
     pipe = PL.UploadPipeline(fc, strategy)
     ev_rng = _event_rng(fc)
     private = SA.wants_private(fc)
+    # FedARA's votes: the upload program scores the cohort's slots where
+    # the importance method allows (``method``); else the host scores them
+    method = strategy.device_importance()
+    host_scores = strategy.uses_masks() and method is None
+    layout = (IMP.score_layout(trainable["adapters"], strategy.n_experts)
+              if method else None)
 
     for rnd in range(s1_rounds, fc.rounds):
         rsp = history.begin_round(rnd)
@@ -115,8 +126,14 @@ def run_cohort(model, strategy, parts, train, test, fc,
         # ---- local phase: one dispatch for the whole cohort --------------
         cohort = CH.build_cohort(train, parts, active, fc, rnd, c_pad,
                                  ndev=ndev)
-        pc = gc = lc = mc = avg = None
+        pc = gc = lc = mc = avg = scores = None
         cohort_idx = {}
+        # the round aggregates on the host — from every client's delta
+        # tree — with a codec, privacy or a sub-batch client; otherwise
+        # the on-device FedAvg is the aggregate and the upload program's
+        # scores and norms are all the host reads of the cohort's slots
+        host_agg = (pipe.codec is not None or private or cohort is None
+                    or bool(cohort.fallback))
         if cohort is not None:
             plan = CH.schedule(cohort.n_steps, cohort.step_mask.shape[1],
                                lanes, ndev)
@@ -141,18 +158,24 @@ def run_cohort(model, strategy, parts, train, test, fc,
                 cohort.weights)
             dsp.end()
             cohort_idx = dict(zip(cohort.cids, cohort.slots))
+            scores, norms = rp.upload(
+                pc, gc if method and IMP.reads_grads(method) else None, bc,
+                masks)
             # ONE batched device→host pull for everything the host path
-            # reads — cohort params, broadcast ref, (optional) grads, and
-            # the loss/metric stacks; the per-client params/deltas below
-            # are host-side slices of these, not per-leaf transfers inside
-            # the client loop.
-            pull = (pc, bc,
-                    gc if strategy.uses_masks() and gc is not None else None,
-                    lc, mc, counts)
+            # reads: the loss/metric stacks, the upload program's scores
+            # and norms and, only where the host needs them, the slots'
+            # params, the broadcast and grads (host importance scores,
+            # host aggregation); the per-client trees below are host-side
+            # slices of these, not per-leaf transfers inside the loop.
+            pull = (pc if host_agg or host_scores else None,
+                    bc if host_agg else None,
+                    gc if host_scores else None,
+                    lc, mc, counts, scores, norms)
             ksp = tr.begin("cohort.pull", kind="stage")
             if tr.enabled:
                 ksp.count(nbytes=sum(x.nbytes for x in jax.tree.leaves(pull)))
-            pc_host, bc_host, gc_host, lc, mc, counts = jax.device_get(pull)
+            pc_host, bc_host, gc_host, lc, mc, counts, scores, norms = \
+                jax.device_get(pull)
             # the model's counts: held experts' pairs and estimated rows,
             # whose ratio estimates the expert fill share
             ksp.count(**{k: round(float(v)) for k, v in counts.items()})
@@ -162,21 +185,27 @@ def run_cohort(model, strategy, parts, train, test, fc,
         usp = tr.begin("upload", kind="stage")
         if cohort is not None:
             lc, mc = np.asarray(lc, np.float32), np.asarray(mc, np.float32)
-            dc = jax.tree.map(
-                lambda p, b: np.asarray(p, np.float32)
-                - np.asarray(b, np.float32), pc_host, bc_host)
+            if host_agg:
+                dc = jax.tree.map(
+                    lambda p, b: np.asarray(p, np.float32)
+                    - np.asarray(b, np.float32), pc_host, bc_host)
+            else:
+                # the identity wire's bytes: shapes and masks alone
+                nbytes = strategy.comm_up(bc, masks_np)
 
         losses, local_masks, encoded = [], [], []
-        up = 0
+        up = n_dev_scores = n_host_deltas = 0
         for cid in active:
             csp = history.begin_client(cid)
+            params_k = grads_k = delta_k = None
             if cid in cohort_idx:
                 i = cohort_idx[cid]
                 sm = cohort.step_mask[i]
-                params_k = CH.slice_client(pc_host, i)
-                grads_k = CH.slice_client(gc_host, i) \
-                    if gc_host is not None else None
-                delta_k = CH.slice_client(dc, i)
+                if host_scores:
+                    params_k = CH.slice_client(pc_host, i)
+                    grads_k = CH.slice_client(gc_host, i)
+                if host_agg:
+                    delta_k = CH.slice_client(dc, i)
                 m = {"loss": float(np.mean(lc[i][sm])) if sm.any()
                      else float("nan"),
                      "metric": float(np.mean(mc[i][sm])) if sm.any()
@@ -202,22 +231,32 @@ def run_cohort(model, strategy, parts, train, test, fc,
             lm = None
             if strategy.uses_masks():
                 isp = tr.begin("importance", kind="stage")
-                lm = strategy.local_masks(
-                    rnd, params_k["adapters"],
-                    (grads_k or {}).get("adapters"), run.n_rank_units)
+                if params_k is None:                # the upload program's
+                    lm = strategy.masks_from_scores(
+                        rnd, scores[i], layout, run.n_rank_units)
+                    n_dev_scores += 1
+                else:
+                    lm = strategy.local_masks(
+                        rnd, params_k["adapters"],
+                        (grads_k or {}).get("adapters"), run.n_rank_units)
                 isp.end()
                 local_masks.append(lm)
             upd = PL.ClientUpdate(int(cid), delta_k,
                                   weight=w, votes=lm,
                                   n_steps=m["n_batches"])
             esp = tr.begin("encode", kind="stage")
-            enc = pipe.encode(upd, masks_np)
+            if delta_k is None:
+                enc = pipe.encode_reduced(upd, float(norms[i]), nbytes)
+            else:
+                enc = pipe.encode(upd, masks_np)
+                n_host_deltas += 1
             esp.end()
             up += enc.nbytes
             encoded.append(enc)
             losses.append(m["loss"])
             csp.end(n_steps=m["n_batches"], up_bytes=enc.nbytes,
                     loss=m["loss"])
+        usp.count(device_scores=n_dev_scores, host_deltas=n_host_deltas)
         usp.end()
 
         # ---- aggregation: on-device psum unless a side path was taken ----
@@ -234,13 +273,12 @@ def run_cohort(model, strategy, parts, train, test, fc,
             down += agg.down_bytes
             protocol_s = agg.time_s
         elif encoded:
-            if pipe.codec is None and cohort is not None \
-                    and not cohort.fallback:
+            if host_agg:
+                trainable = pipe.aggregate(bc, encoded, rnd=rnd)
+            else:
                 # identity wire: the on-device psum FedAvg equals the
                 # pipeline's delta-space mean (Σŵ(bc+Δ) = bc + ΣŵΔ)
                 trainable = avg
-            else:
-                trainable = pipe.aggregate(bc, encoded, rnd=rnd)
             trainable, masks, masks_np = SV._arbitrate(
                 strategy, trainable, local_masks, masks, masks_np, rnd,
                 rp.prune)

@@ -292,6 +292,14 @@ def flatten_update(trainable: Any, masks_np: Any | None) -> np.ndarray:
     return np.concatenate([ad] + rest) if rest else ad
 
 
+def wire_norm(delta: Any, masks: Any | None):
+    """``‖flatten_update(delta, masks)‖₂`` as traceable jax, in float32."""
+    rest = jax.tree.leaves({k: v for k, v in delta.items() if k != "adapters"})
+    return jnp.sqrt(COMM.sq_sum(delta.get("adapters", {}), masks)
+                    + sum(jnp.sum(jnp.square(jnp.asarray(x, jnp.float32)))
+                          for x in rest))
+
+
 def unflatten_update(wire: np.ndarray, like: Any, masks_np: Any | None) -> Any:
     """Inverse of flatten_update; masked adapter ranks come back as zeros."""
     n_ad = COMM.count_params(like.get("adapters", {}), masks_np)

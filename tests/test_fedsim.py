@@ -147,6 +147,134 @@ def test_cohort_outputs_are_freed_before_the_next_dispatch(setup,
     assert len(held) == 3 and held == [0, 0, 0]
 
 
+def _traced_run(setup, parts, strat, **fc_kw):
+    """A traced 3-round cohort run on ``parts``, like ``_run``; returns
+    (history, events, metrics, upload-program inputs (pc, bc, masks) and
+    cohort of each round)."""
+    from repro import obs
+    from repro.federated import server as SV
+    cfg, train, test, _ = setup
+    real_programs, real_build = SV.round_programs, CH.build_cohort
+    seen, cohorts = [], []
+
+    def programs(*a, **kw):
+        rp = real_programs(*a, **kw)
+        if rp.upload is None:
+            return rp
+
+        def upload(pc, gc, bc, masks):
+            seen.append(jax.device_get((pc, bc, masks)))
+            return rp.upload(pc, gc, bc, masks)
+        return rp._replace(upload=upload)
+
+    def build(*a, **kw):
+        cohorts.append(real_build(*a, **kw))
+        return cohorts[-1]
+
+    strat.total_rounds, strat.warmup_rounds = 3, 1
+    strat.final_rounds_frac = 0.34
+    model = Model(cfg, peft=strat.peft, unroll=True)
+    fc = FedConfig(**{"clients_per_round": 3, "max_local_batches": 3,
+                      **fc_kw}, rounds=3, batch_size=16, eval_every=3,
+                   lr=3e-3, runner="cohort")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(SV, "round_programs", programs)
+        mp.setattr(CH, "build_cohort", build)
+        tr = obs.configure(None, health=False, profile=False)
+        try:
+            h = run_federated(model, strat, parts, train, test, fc)
+            evs = list(tr.events())
+        finally:
+            obs.disable()
+    return h, evs, tr.metrics, seen, cohorts, fc
+
+
+def _uploads(evs):
+    """Per round, in order: (clients, sub-batch clients, upload counters,
+    cohort.pull bytes)."""
+    spans = [e for e in evs if e.get("type") == "span"]
+    rounds = [e["id"] for e in spans if e["name"] == "round"]
+    by_id = {e["id"]: e for e in spans}
+
+    def round_of(e):
+        while e["parent"] not in rounds:
+            e = by_id[e["parent"]]
+        return e["parent"]
+    out = {r: [0, 0, None, None] for r in rounds}
+    for e in spans:
+        if e["name"] in ("client", "fallback"):
+            out[round_of(e)][e["name"] == "fallback"] += 1
+        elif e["name"] == "upload":
+            out[round_of(e)][2] = e["attrs"]
+        elif e["name"] == "cohort.pull":
+            out[round_of(e)][3] = e["attrs"]["nbytes"]
+    return [out[r] for r in rounds]
+
+
+@pytest.fixture(scope="module")
+def device_run(setup):
+    """FedARA on clients that all fill a batch: identity wire, no
+    sub-batch client, so every round aggregates on the device."""
+    from repro.federated.partition import iid_partition
+    parts = iid_partition(setup[1].labels, 10, seed=0)
+    assert min(len(p) for p in parts) >= 16
+    return _traced_run(setup, parts, all_strategies(rounds=3)["fedara"])
+
+
+def test_device_aggregated_rounds_pull_no_slot_tree(device_run):
+    """No round builds a delta tree on the host, every client's votes come
+    from the upload program, and ``cohort.pull`` copies the loss stacks,
+    scores and norms alone."""
+    h, evs, _, seen, cohorts, fc = device_run
+    n_ranks = sum(np.asarray(m).size for m in jax.tree.leaves(h["masks"]))
+    C, T = 3, fc.max_local_batches
+    rounds = _uploads(evs)
+    assert len(rounds) == 3 and len(seen) == 3
+    for n, small, up, nbytes in rounds:
+        assert n == 3 and small == 0
+        assert up == {"device_scores": 3, "host_deltas": 0}
+        assert nbytes == 4 * C * (2 * T + n_ranks + 1)
+
+
+def test_device_reduced_uploads_account_as_the_host_encode(device_run):
+    """The ``encode`` events, ``pipeline.up_bytes`` and ``pipeline.updates``
+    of a device-aggregated run are those the host's ``encode`` gives on
+    the deltas it no longer builds: same clients, bytes, norms."""
+    from repro.fedsim import pipeline as PL
+    h, evs, metrics, seen, cohorts, fc = device_run
+    pipe = PL.UploadPipeline(fc, all_strategies(rounds=3)["fedara"])
+    want = []
+    for (pc, bc, masks), cohort in zip(seen, cohorts):
+        masks_np = jax.tree.map(np.asarray, masks)
+        for cid, i in zip(cohort.cids, cohort.slots):
+            delta = PL.delta_tree(CH.slice_client(pc, i), bc)
+            enc = pipe.encode(PL.ClientUpdate(cid, delta, 1.0), masks_np)
+            want.append((cid, enc.nbytes, enc.norm))
+    got = [(e["attrs"]["cid"], e["attrs"]["nbytes"], e["attrs"]["norm"])
+           for e in evs if e.get("type") == "event" and e["name"] == "encode"]
+    assert [g[:2] for g in got] == [w[:2] for w in want]
+    np.testing.assert_allclose([g[2] for g in got], [w[2] for w in want],
+                               rtol=1e-5)
+    labels = {"codec": "identity", "stage": "stage2"}
+    assert metrics.counter("pipeline.up_bytes", **labels).value \
+        == sum(w[1] for w in want) == sum(r.up_bytes for r in h["rounds"])
+    assert metrics.counter("pipeline.updates", **labels).value == len(want)
+
+
+def test_sensitivity_scores_on_the_host(setup):
+    """Sensitivity's EMA runs across clients, so the upload program scores
+    nothing: every vote is the host's, from the pulled params and grads."""
+    strat = all_strategies(rounds=3)["fedara"]
+    strat.importance = "sensitivity"
+    h, evs, *_ = _traced_run(setup, setup[3], strat)
+    rounds = _uploads(evs)
+    assert len(rounds) == 3
+    for n, small, up, _ in rounds:
+        assert up == {"device_scores": 0,
+                      "host_deltas": n if small else 0}
+    assert all(r.live_ranks > 0 for r in h["rounds"])
+
+
 def test_cohort_simulates_stragglers_and_dropout(setup):
     h = _run(setup, "cohort", strategy="fedlora", dropout=0.3,
              straggler=0.5, event_seed=3)

@@ -179,14 +179,18 @@ def test_programs_carry_stable_names():
 # a traced cohort run: the round's stages, in order, on the host plane
 # ---------------------------------------------------------------------------
 
+def _cohort_cfg():
+    from repro.configs.distilbert import MINI
+    return MINI.with_(n_layers=2, layer_pattern=("attn",) * 2)
+
+
 @pytest.fixture(scope="module")
 def cohort_trace(tmp_path_factory):
-    from repro.configs.distilbert import MINI
     from repro.data.synthetic import make_classification
     from repro.federated.baselines import all_strategies
     from repro.federated.server import FedConfig, run_federated
     from repro.models import Model
-    cfg = MINI.with_(n_layers=2, layer_pattern=("attn",) * 2)
+    cfg = _cohort_cfg()
     train = make_classification(500, 20, cfg.vocab_size, 32, seed=1)
     test = make_classification(64, 20, cfg.vocab_size, 32, seed=2)
     order = np.random.default_rng(0).permutation(len(train.labels))
@@ -290,3 +294,33 @@ def test_cohort_dispatch_counts_steps_and_slots(cohort_trace):
         assert st["steps"] <= st["slots"]
         assert st["slots"] <= -(-fc.clients_per_round
                                 * fc.max_local_batches // lanes) * lanes
+
+
+def test_upload_counts_where_votes_and_deltas_are_made(cohort_trace):
+    """Every cohort client's votes come from the upload program.  A round
+    with no sub-batch client builds no delta tree on the host and pulls
+    no slot tree, only the loss stacks, scores and norms; a round with one
+    aggregates on the host from every client's delta tree, and pulls the
+    slots' params and the broadcast, never the grads."""
+    from repro.models import Model
+    evs, host, parts, fc = cohort_trace
+    model = Model(_cohort_cfg(), peft="bea", unroll=True)
+    _, t_shapes = jax.eval_shape(model.init, jax.random.key(0))
+    tree_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(t_shapes))
+    n_ranks = sum(x.size for x in jax.tree.leaves(
+        jax.eval_shape(model.init_masks)))
+    C, T = fc.clients_per_round, fc.max_local_batches * fc.local_epochs
+    small_bytes = 4 * C * (2 * T + n_ranks + 1)     # lc, mc, scores, norms
+    kinds = set()
+    for rnd, inside in _by_round(host).items():
+        n = sum(1 for name, *_ in inside if name == "client")
+        small = sum(1 for name, *_ in inside if name == "fallback")
+        (up,) = [st for name, _, _, st in inside if name == "upload"]
+        (pull,) = [st for name, _, _, st in inside if name == "cohort.pull"]
+        assert up["device_scores"] == n - small, rnd
+        assert up["host_deltas"] == (n if small else 0), rnd
+        slot_trees = (C + 1) * tree_bytes if small else 0
+        assert pull["nbytes"] == small_bytes + slot_trees, rnd
+        kinds.add(bool(small))
+    assert kinds == {True, False}

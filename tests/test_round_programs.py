@@ -134,3 +134,66 @@ def test_round_programs_match_eager_ops(case):
         _assert_on((after, m2), rep)
         assert rp.prune._cache_size() == 1
     assert rp.place._cache_size() == 1
+
+
+def _slots_like(tree, masks_np, seed):
+    """SLOTS per-client trees around ``tree``, stacked: each perturbed,
+    and every other one with its masked ranks pruned to exactly zero."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for s in range(SLOTS):
+        t = jax.tree.map(lambda x: x + rng.standard_normal(x.shape).astype(
+            x.dtype), tree)
+        if s % 2 and masks_np is not None:
+            t = dict(t, adapters=jax.device_get(COMM.prune_tree(
+                t["adapters"], masks_np)))
+        out.append(t)
+    return jax.tree.map(lambda *x: np.stack(x), *out)
+
+
+@pytest.mark.parametrize("method", ["mag", "grad", "mixed"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_upload_program_matches_host_scores_and_wire_norms(case, method):
+    """The upload program's per-slot scores are the host's flattened
+    ``score_tree`` (expert axes averaged, pruned ranks exactly zero) and
+    its norms those of the host's identity wire ``pc − bc``; new mask
+    values compile nothing."""
+    from repro.core import importance as IMP
+    from repro.fedsim import transport as T
+    strategy, trainable, masks_np, masks_np2 = CASES[case]()
+    if strategy.uses_masks():
+        strategy.importance = method
+        strategy.n_experts = 3 if case == "kimi_experts" else 0
+    mesh = CH.cohort_mesh()
+    rep = NamedSharding(mesh, P())
+    lanes = NamedSharding(mesh, P("clients"))
+    rp = round_programs(strategy, rep, slots=SLOTS, slot_sharding=lanes)
+    bc = trainable if masks_np is None else dict(trainable, adapters=(
+        jax.device_get(COMM.prune_tree(trainable["adapters"], masks_np))))
+    pc = _slots_like(bc, masks_np, 11)
+    gc = _slots_like(bc, masks_np, 12)
+    reads = IMP.reads_grads(method) and strategy.uses_masks()
+
+    for mnp in (masks_np, masks_np2):
+        bc_d, masks = pin_params(bc, mnp, sharding=rep)
+        pc_d, gc_d = jax.device_put((pc, gc), lanes)
+        scores, norms = jax.device_get(rp.upload(
+            pc_d, gc_d if reads else None, bc_d, masks))
+        for i in range(SLOTS):
+            p_i, g_i = CH.slice_client(pc, i), CH.slice_client(gc, i)
+            delta = jax.tree.map(lambda a, b: a - b, p_i, bc)
+            want = np.linalg.norm(T.flatten_update(delta, mnp))
+            np.testing.assert_allclose(norms[i], want, rtol=1e-5)
+            if not strategy.uses_masks():
+                assert scores is None
+                continue
+            tree, _ = IMP.score_tree(p_i["adapters"], g_i["adapters"],
+                                     method, n_experts=strategy.n_experts)
+            flat, layout = IMP.flat_concat(tree)
+            assert layout == IMP.score_layout(bc["adapters"],
+                                              strategy.n_experts)
+            np.testing.assert_allclose(scores[i], flat, rtol=1e-6, atol=0)
+            if i % 2:      # ranks pruned by masks_np score exactly 0
+                dead = ~IMP.flat_concat(masks_np)[0].astype(bool)
+                assert dead.any() and not scores[i][dead].any()
+    assert rp.upload._cache_size() == 1
